@@ -90,6 +90,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     ap.add_argument("--json", metavar="PATH",
                     help="also write the rows as a JSON artifact")
     args = ap.parse_args(argv)
+
+    from repro.api.cache import use_compilation_cache
+    use_compilation_cache()
     unknown = sorted(set(args.models) - set(SUITE))
     if unknown:
         raise SystemExit(f"unknown models {unknown}; "

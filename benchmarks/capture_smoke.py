@@ -45,6 +45,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     ap.add_argument("--autotune-budget-ms", type=float, default=1000.0)
     ap.add_argument("--batch-size", type=int, default=1)
     args = ap.parse_args(argv)
+
+    from repro.api.cache import use_compilation_cache
+    use_compilation_cache()
     if args.config not in SUITE:
         raise SystemExit(f"unknown config {args.config!r}; "
                          f"choose from {sorted(SUITE)}")

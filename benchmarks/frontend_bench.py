@@ -89,6 +89,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     ap.add_argument("--json", metavar="PATH")
     args = ap.parse_args(argv)
 
+    from repro.api.cache import use_compilation_cache
+    use_compilation_cache()
+
     rows = run(args.layers, args.width)
     hdr = (f"{'layers':>7} {'build ms':>9} {'µs/layer':>9} "
            f"{'trace ms':>9} {'µs/layer':>9} {'max err':>9}")

@@ -104,6 +104,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "artifact (what the CI precision gate consumes)")
     args = ap.parse_args(argv)
 
+    from repro.api.cache import use_compilation_cache
+    use_compilation_cache()
+
     act = activation_errors()
     print("fast-activation errors (paper §3.4):")
     for fn, e in act.items():

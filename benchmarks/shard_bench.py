@@ -191,6 +191,9 @@ def main(argv=None) -> int:
                     help="do not append this run to the perf trajectory")
     args = ap.parse_args(argv)
 
+    from repro.api.cache import use_compilation_cache
+    use_compilation_cache()
+
     import jax
 
     mesh_spec = _pick_mesh(args.mesh)

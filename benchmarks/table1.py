@@ -190,6 +190,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "artifact (the CI perf-trajectory format)")
     args = ap.parse_args(argv)
 
+    from repro.api.cache import use_compilation_cache
+    use_compilation_cache()
+
     rows = run(reps=args.reps, configs=args.configs,
                autotune=args.autotune,
                autotune_budget_ms=args.autotune_budget_ms,

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import pathlib
 import pickle
 import tempfile
 from typing import Optional
@@ -45,6 +46,29 @@ def cache_key(*parts: str) -> str:
     return h.hexdigest()
 
 
+#: JAX's own persistent compilation cache, when the environment names
+#: none: one fixed path inside the checkout, so that every run from the
+#: checkout finds what the runs before it compiled.
+COMPILATION_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def use_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.
+
+    Where ``$JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and
+    nothing is changed; otherwise the cache goes to
+    :data:`COMPILATION_CACHE_DIR`.  Entry points call this before their
+    first compile; importing the package never does.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILATION_CACHE_DIR)
+    return COMPILATION_CACHE_DIR
+
+
 def resolve_cache_dir(explicit: Optional[str]) -> Optional[str]:
     """Explicit option wins; else ``$REPRO_CACHE_DIR``; else disabled."""
     return explicit if explicit is not None else os.environ.get("REPRO_CACHE_DIR")
@@ -62,8 +86,13 @@ class ExecutableCache:
     def _path(self, key: str) -> str:
         return os.path.join(self.root, f"{key}.xla")
 
-    def load(self, key: str):
-        """Return a loaded executable, or None on miss/failure."""
+    def load(self, key: str, devices=None):
+        """Return a loaded executable, or None on miss/failure.
+
+        ``devices`` are the devices the executable runs on, in its
+        device-assignment order (a sharded program's mesh); ``None``
+        loads onto every local device, right for a single-device one.
+        """
         path = self._path(key)
         if not os.path.exists(path):
             self.misses += 1
@@ -72,7 +101,8 @@ class ExecutableCache:
             from jax.experimental import serialize_executable as se
             with open(path, "rb") as f:
                 payload = pickle.load(f)
-            exe = se.deserialize_and_load(*payload)
+            exe = se.deserialize_and_load(*payload,
+                                          execution_devices=devices)
             self.hits += 1
             try:
                 # LRU recency: a hit refreshes the entry's mtime so the

@@ -324,6 +324,11 @@ class JitExecutable(GraphExecutable):
         executables re-place call arguments here)."""
         return fn
 
+    def _execution_devices(self):
+        """Devices a cached program is loaded onto, in assignment
+        order (None = the default device, the unsharded case)."""
+        return None
+
     # -- compilation ---------------------------------------------------
     def _resolve_selection(self, batch_size: int, *,
                            probe: bool = False):
@@ -429,7 +434,8 @@ class JitExecutable(GraphExecutable):
 
         jitted = jax.jit(program, donate_argnums=donate)
         key = self._key(batch_size, selection)
-        exe = self._disk.load(key) if self._disk else None
+        exe = (self._disk.load(key, self._execution_devices())
+               if self._disk else None)
         if exe is None:
             exe = jitted.lower(*specs).compile()
             if self._disk:

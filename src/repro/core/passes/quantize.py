@@ -51,6 +51,7 @@ import numpy as np
 from ..graph import Graph, Node
 from .manager import register_pass
 from ...kernels import qmath
+from ...kernels.platform import on_tpu
 
 #: Ops the pass may rewrite to low precision.
 QUANT_OPS = ("dense", "conv2d")
@@ -66,11 +67,6 @@ DEFAULT_PRECISION_BUDGET = 0.05
 DEFAULT_MEASURE_BUDGET_MS = 1000.0
 
 _MODES = ("f32", "bf16", "int8", "mixed")
-
-
-def _on_tpu() -> bool:
-    import jax
-    return any(d.platform == "tpu" for d in jax.devices())
 
 
 def _kernel_out_axis(node: Node) -> int:
@@ -345,7 +341,7 @@ def quantize(graph: Graph) -> Tuple[Graph, Dict]:
         raise ValueError(f"unknown quantization mode {mode!r}; "
                          f"expected one of {_MODES}")
     method = req.get("method", "absmax")
-    on_tpu = _on_tpu()
+    tpu = on_tpu()
     sites = [n for n in graph.nodes
              if n.op in QUANT_OPS and "kernel" in n.params]
 
@@ -362,10 +358,10 @@ def quantize(graph: Graph) -> Tuple[Graph, Dict]:
 
     if mode == "mixed" and sites:
         decisions, tune_report = _tune_mixed(
-            sites, graph, ranges, first_inputs, req, on_tpu, method)
+            sites, graph, ranges, first_inputs, req, tpu, method)
         stats["mixed"] = tune_report
     else:
-        decisions = {n.name: (_static_site_mode(n, mode, on_tpu) or "f32")
+        decisions = {n.name: (_static_site_mode(n, mode, tpu) or "f32")
                      for n in sites}
 
     for node in sites:

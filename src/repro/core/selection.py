@@ -38,14 +38,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Tuple
 
-import jax
 import numpy as np
 
 from .graph import Graph
+from ..kernels.platform import on_tpu
 from ..kernels.tiles import (LANE, VMEM_BUDGET_BYTES, block_vmem_bytes,
                              ceil_to, pick_block, sublane_for)
-
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
 
 #: Padded-MACs / logical-MACs bound for the fused matmul (lane/sublane
 #: granule waste).  The table-1 suite's smallest head (32×2, batch 1)
@@ -90,7 +88,7 @@ def _select_dense(node, in_spec, batch_size: int, n: int) -> KernelChoice:
     qm = node.attrs.get("quant.mode")
     itemsize = {"int8": 1, "bf16": 2}.get(
         qm, int(np.dtype(in_spec.dtype).itemsize))
-    if qm == "int8" and not _ON_TPU:
+    if qm == "int8" and not on_tpu():
         # Backend-aware prior: off-TPU the Pallas q8 kernel only runs in
         # interpret mode, while the reference lax int8 lowering compiles
         # to real vectorized code — the measured winner by a wide margin.
@@ -134,7 +132,7 @@ def _select_activation(node, in_spec, precision: str) -> KernelChoice:
     if fn not in ("tanh", "sigmoid"):
         return KernelChoice(node.name, "activation", "jnp.act",
                             f"fast {fn} has no Pallas kernel form")
-    if not _ON_TPU:
+    if not on_tpu():
         return KernelChoice(
             node.name, "activation", "jnp.act",
             "no TPU: interpret-mode Pallas loses to the jnp reference")

@@ -140,6 +140,9 @@ class ShardedExecutable(JitExecutable):
 
         return call
 
+    def _execution_devices(self):
+        return list(self.mesh.devices.flat)
+
     # -- cache manifest (repro.prune atomic groups) --------------------
     def manifest_key(self) -> str:
         """Identity of this executable's cache-manifest group (all batch

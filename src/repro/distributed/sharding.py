@@ -56,19 +56,8 @@ def current_rules() -> Dict[str, AxisVal]:
 
 
 def current_mesh() -> Optional[Mesh]:
-    mesh = getattr(_local, "mesh", None)
-    if mesh is not None:
-        return mesh
-    # Fall back to the global mesh context (``with mesh:``).
-    env_mesh = jax.sharding.get_abstract_mesh() if hasattr(
-        jax.sharding, "get_abstract_mesh") else None
-    try:
-        from jax.interpreters import pxla
-
-        m = pxla.thread_resources.env.physical_mesh
-        return m if m.devices.size > 1 else None
-    except Exception:
-        return None
+    """The mesh activated by :func:`use_mesh` on this thread, if any."""
+    return getattr(_local, "mesh", None)
 
 
 @contextlib.contextmanager

@@ -8,7 +8,11 @@ KV stream.  The TPU rendition:
 
 * grid (batch, kv_head, S/bs): each instance owns the G = H/Hkv query
   heads of one KV head — the GQA group is the register-batch (§3.3);
-* K/V stream through VMEM in (bs × D) tiles; the online-softmax state
+* K/V stream through VMEM in (bs × D) tiles.  The cache is viewed as
+  (B, S, Hkv·D) — a free reshape — so a tile is one KV head's D lanes
+  of bs rows: its minor dims (bs, D) meet the chip's (8, 128) tiling,
+  which a (bs, 1, D) block of the (B, S, Hkv, D) array cannot;
+* the online-softmax state
   (m, l, acc) lives in VMEM scratch across the S dimension — the
   accumulator never round-trips to HBM (the paper's "results are
   written to the destination addresses" only once per batch);
@@ -45,8 +49,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0]         # (G, D)
-    k = k_ref[0, :, 0, :]   # (bs, D)
-    v = v_ref[0, :, 0, :]   # (bs, D)
+    k = k_ref[0]            # (bs, D)
+    v = v_ref[0]            # (bs, D)
 
     scores = jax.lax.dot_general(
         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
@@ -86,7 +90,7 @@ def decode_attention_p(
     scale: Optional[float] = None,
     fast: bool = False,
     bs: int = DEFAULT_BS,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Pallas single-token decode attention over a padded KV cache:
     one grid step per (batch, kv-head), KV streamed in ``bs``-row
@@ -107,6 +111,7 @@ def decode_attention_p(
     scale = scale if scale is not None else d ** -0.5
 
     qg = q.reshape(b, hkv, g, d)
+    kv_shape = (b, s, hkv * d)
     kernel = functools.partial(
         _decode_kernel, ns=ns, bs=bs, scale=scale, fast=fast
     )
@@ -115,8 +120,8 @@ def decode_attention_p(
         grid=(b, hkv, ns),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda bi, hi, si, lens: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda bi, hi, si, lens: (bi, si, hi, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda bi, hi, si, lens: (bi, si, hi, 0)),
+            pl.BlockSpec((1, bs, d), lambda bi, hi, si, lens: (bi, si, hi)),
+            pl.BlockSpec((1, bs, d), lambda bi, hi, si, lens: (bi, si, hi)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda bi, hi, si, lens: (bi, hi, 0, 0)),
         scratch_shapes=[
@@ -130,5 +135,5 @@ def decode_attention_p(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
         interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
+    )(lengths, qg, k_cache.reshape(kv_shape), v_cache.reshape(kv_shape))
     return out.reshape(b, h, d)

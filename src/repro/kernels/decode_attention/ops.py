@@ -4,13 +4,11 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from . import ref
 from .kernel import decode_attention_p
-
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
+from ..platform import interpret_mode
 
 
 def decode_attention(
@@ -42,6 +40,6 @@ def decode_attention(
         lengths.astype(jnp.int32),
         scale=scale,
         fast=fast,
-        interpret=not _ON_TPU,
+        interpret=interpret_mode(),
         **kw,
     )

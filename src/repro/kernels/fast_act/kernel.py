@@ -58,7 +58,7 @@ def _elementwise_kernel(x_ref, o_ref, *, fn: str):
 
 
 @functools.partial(jax.jit, static_argnames=("fn", "interpret", "block"))
-def fast_act_2d(x: jnp.ndarray, fn: str, interpret: bool = True,
+def fast_act_2d(x: jnp.ndarray, fn: str, *, interpret: bool,
                 block=None) -> jnp.ndarray:
     """Apply a fast activation to a 2D f32/bf16 array via Pallas (the
     output dtype matches the input; internals are f32 either way).

@@ -1,7 +1,7 @@
 """Public jit'd wrappers for the fast activations.
 
 ``fast_act(x, fn)`` reshapes any-rank input to 2D, dispatches to the
-Pallas kernel (interpret=True on CPU, compiled on TPU), and restores the
+Pallas kernel (interpreted on the CPU, compiled on a TPU), and restores the
 shape.  ``use_pallas=False`` falls back to the pure-jnp reference (same
 math — used by the CPU-side CompiledNN back end where interpret-mode
 Pallas would be needlessly slow).
@@ -11,13 +11,11 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from . import ref
 from .kernel import fast_act_2d
-
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
+from ..platform import interpret_mode
 
 
 def fast_act(x: jnp.ndarray, fn: str, use_pallas: bool = False,
@@ -45,7 +43,7 @@ def fast_act(x: jnp.ndarray, fn: str, use_pallas: bool = False,
         x2 = x.reshape(-1, shape[-1])
     if not narrow:
         x2 = x2.astype(jnp.float32)
-    y = fast_act_2d(x2, fn, interpret=not _ON_TPU, block=block)
+    y = fast_act_2d(x2, fn, interpret=interpret_mode(), block=block)
     return y.reshape(shape)
 
 

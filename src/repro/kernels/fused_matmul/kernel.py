@@ -29,12 +29,17 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..fast_act.kernel import _BODIES as _FAST_BODIES
 
 DEFAULT_BM = 256
 DEFAULT_BK = 512
 DEFAULT_BN = 256
+
+#: Grid (M, N, K): output tiles are independent, K accumulates in order.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _apply_epilogue(acc, bias_ref, fn: Optional[str], fast: bool,
@@ -117,7 +122,7 @@ def fused_matmul_p(
     fast: bool = False,
     w_layout: str = "io",
     block: Tuple[int, int, int] = (DEFAULT_BM, DEFAULT_BK, DEFAULT_BN),
-    interpret: bool = True,
+    interpret: bool,
     attrs: Optional[dict] = None,
 ) -> jnp.ndarray:
     """Raw pallas_call: operands must already be tile-aligned.
@@ -166,10 +171,8 @@ def fused_matmul_p(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pl.pallas_tpu.VMEM((bm, bn), jnp.float32)]
-        if hasattr(pl, "pallas_tpu")
-        else [_vmem_scratch((bm, bn))],
-        compiler_params=_compiler_params(),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
 
@@ -233,7 +236,7 @@ def fused_matmul_q8_p(
     fast: bool = False,
     w_layout: str = "io",
     block: Tuple[int, int, int] = (DEFAULT_BM, DEFAULT_BK, DEFAULT_BN),
-    interpret: bool = True,
+    interpret: bool,
     attrs: Optional[dict] = None,
 ) -> jnp.ndarray:
     """Raw int8 pallas_call: operands must already be quantized and
@@ -285,26 +288,8 @@ def fused_matmul_q8_p(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pl.pallas_tpu.VMEM((bm, bn), jnp.int32)]
-        if hasattr(pl, "pallas_tpu")
-        else [_vmem_scratch((bm, bn), jnp.int32)],
-        compiler_params=_compiler_params(),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
 
-
-def _vmem_scratch(shape, dtype=jnp.float32):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
-def _compiler_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except Exception:  # pragma: no cover - older pallas versions
-        return None

@@ -8,22 +8,15 @@ by test (tests/test_kernels.py sweeps shapes × epilogues).
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from . import ref
 from .kernel import fused_matmul_p, fused_matmul_q8_p
+from ..platform import interpret_mode
 from ..tiles import pick_block
 from ..qmath import dequant_scales, quantize_q8
-
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-
-#: Compat alias: block choice moved to kernels.tiles so the compile-time
-#: kernel selector reasons about exactly the blocks used here.
-_pick_block = pick_block
 
 
 def _pad_to(a: jnp.ndarray, m0: int, m1: int) -> jnp.ndarray:
@@ -75,7 +68,7 @@ def fused_matmul(
 
     m = x2.shape[0]
     itemsize = jnp.dtype(compute).itemsize
-    bm, bk, bn = block if block is not None else _pick_block(m, k, n, itemsize)
+    bm, bk, bn = block if block is not None else pick_block(m, k, n, itemsize)
     xp = _pad_to(x2, bm, bk)
     wp = _pad_to(w, bk if w_layout == "io" else bn, bn if w_layout == "io" else bk)
     pn = wp.shape[1] if w_layout == "io" else wp.shape[0]
@@ -95,7 +88,7 @@ def fused_matmul(
         fast=fast,
         w_layout=w_layout,
         block=(bm, bk, bn),
-        interpret=not _ON_TPU,
+        interpret=interpret_mode(),
         attrs=attrs,
     )
     return y[:m, :n].reshape(shape[:-1] + (n,))
@@ -147,7 +140,7 @@ def fused_matmul_q8(
         return y.reshape(shape[:-1] + (n,))
 
     m = x2.shape[0]
-    bm, bk, bn = block if block is not None else _pick_block(m, k, n, 1)
+    bm, bk, bn = block if block is not None else pick_block(m, k, n, 1)
     xp = _pad_to(xq, bm, bk)
     wp = _pad_to(wq, bk if w_layout == "io" else bn,
                  bn if w_layout == "io" else bk)
@@ -169,7 +162,7 @@ def fused_matmul_q8(
         fast=fast,
         w_layout=w_layout,
         block=(bm, bk, bn),
-        interpret=not _ON_TPU,
+        interpret=interpret_mode(),
         attrs=attrs,
     )
     return y[:m, :n].reshape(shape[:-1] + (n,))

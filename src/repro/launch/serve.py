@@ -93,6 +93,9 @@ def main(argv=None) -> int:
                     help="print the metrics summary as JSON")
     args = ap.parse_args(argv)
 
+    from ..api.cache import use_compilation_cache
+    use_compilation_cache()
+
     import repro
     from repro.configs import get_config
     from repro.serve import Request

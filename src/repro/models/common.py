@@ -362,24 +362,13 @@ def row_parallel_out(h, w, tp_psum: bool = False):
                            preferred_element_type=hl.dtype)
             return jax.lax.psum(y, "model")
 
-        return _shard_map(fn, mesh=mesh,
-                          in_specs=(P(bspec, None, "model"),
-                                    P("model", None)),
-                          out_specs=P(bspec, None, None),
-                          **{_CHECK_KW: False})(h, wc)
+        return jax.shard_map(fn, mesh=mesh,
+                             in_specs=(P(bspec, None, "model"),
+                                       P("model", None)),
+                             out_specs=P(bspec, None, None),
+                             check_vma=False)(h, wc)
     return jnp.einsum("bsf,fd->bsd", h, w.astype(h.dtype),
                       preferred_element_type=h.dtype)
-
-
-try:  # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_CHECK_KW = ("check_vma" if "check_vma" in
-             _inspect.signature(_shard_map).parameters else "check_rep")
 
 
 def col_parallel_in(x, weights, tp_psum: bool = False):
@@ -411,11 +400,11 @@ def col_parallel_in(x, weights, tp_psum: bool = False):
                                 preferred_element_type=xl.dtype)
                      for w in wl)
 
-    outs = _shard_map(
+    outs = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec, None, None),) + (P(None, "model"),) * len(weights),
         out_specs=(P(bspec, None, "model"),) * len(weights),
-        **{_CHECK_KW: False})(x, *[w.astype(x.dtype) for w in weights])
+        check_vma=False)(x, *[w.astype(x.dtype) for w in weights])
     return list(outs)
 
 

@@ -37,18 +37,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..distributed.sharding import current_mesh, logical
 
-try:  # jax >= 0.4.35
-    from jax import shard_map as _shard_map_mod
-    shard_map = _shard_map_mod
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-#: jax renamed check_rep -> check_vma; pass whichever this version takes.
-_CHECK_KW = ("check_vma" if "check_vma" in
-             _inspect.signature(shard_map).parameters else "check_rep")
-
 
 def moe_init(key, cfg):
     d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
@@ -204,11 +192,11 @@ def moe_apply(p, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
         wspec = (P("model", None, None) if ep else P(None, None, "model"))
         wospec = (P("model", None, None) if ep else P(None, "model", None))
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(bspec, None), P(None, None), wspec, wspec, wospec),
             out_specs=(P(bspec, None), P()),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )(flat, p["router"], p["wi_gate"], p["wi_up"], p["wo"])
         aux = aux.reshape(())
 

@@ -72,16 +72,12 @@ def test_collective_parse_from_sharded_program():
     an all-reduce in the compiled HLO."""
     import jax
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     mesh = jax.make_mesh((1,), ("x",))
 
     def f(a):
         return jax.lax.psum(a, "x")
 
-    sm = shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
+    sm = jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
     compiled = jax.jit(sm).lower(jnp.ones((4,))).compile()
     hbm, coll, unresolved = H.bytes_from_post(compiled.as_text())
     assert hbm > 0

@@ -59,18 +59,28 @@ def test_fused_matmul_higher_rank(rng):
 def test_fused_matmul_bf16_operands_match_upcast(rng):
     """bf16 operands stay bf16 in the kernel (half the VMEM bytes, as
     tiles.block_vmem_bytes models) and match the f32-upcast reference
-    exactly — bf16 products are exact in the f32 accumulator."""
+    to f32 summation order.
+
+    Each bf16 product is exact in f32, but XLA's CPU backend runs a
+    bf16×bf16→f32 dot through a different routine than the reference's
+    f32×f32 dot, and the two add the K products in different orders —
+    plain ``jnp.dot`` on the same operands differs the same way, with
+    no Pallas involved.  So the bound is the one for reordering a sum
+    of K terms in f32: K·eps·Σ|x·w| per element."""
     import jax.numpy as jnp
 
-    x = jnp.asarray(rng.standard_normal((16, 64)), jnp.bfloat16)
-    w = jnp.asarray(rng.standard_normal((64, 32)) * 0.1, jnp.bfloat16)
+    k = 64
+    x = jnp.asarray(rng.standard_normal((16, k)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((k, 32)) * 0.1, jnp.bfloat16)
     b = rng.standard_normal(32).astype(np.float32)
     got = fused_matmul(x, w, b, fn="relu", use_pallas=True)
+    x32, w32 = np.asarray(x, np.float32), np.asarray(w, np.float32)
     want = fm_ref.fused_matmul_ref(
-        np.asarray(x, np.float32), np.asarray(w, np.float32), b, None, None,
+        x32, w32, b, None, None,
         fn="relu", fast=False, w_layout="io", attrs={})
     assert got.dtype == jnp.float32
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+    bound = k * np.finfo(np.float32).eps * (np.abs(x32) @ np.abs(w32))
+    assert np.all(np.abs(np.asarray(want) - np.asarray(got)) <= bound)
 
 
 # ---------------------------------------------------------------------------

@@ -104,9 +104,16 @@ def test_golden_identity_across_targets(graph_fn, prec, rng):
         exe = repro.compile(g, CompileOptions(target=tgt, precision=prec))
         outs[tgt] = np.asarray(exe(input=x)[out])
     # jit and pallas trace the same annotated graph through the same
-    # shared quant expressions; int8's i32 accumulation is exact under
-    # any blocking, so these two are bit-identical.
-    np.testing.assert_array_equal(outs["jit"], outs["pallas"])
+    # shared quant expressions.  int8's i32 accumulation is exact under
+    # any blocking, so there the two are bit-identical.  A float dot's
+    # f32 sum is not: the Pallas kernel contracts K padded to the
+    # 128-lane tile in blocks, the jit program the bare K in one dot,
+    # and XLA adds the products in a different order for each shape —
+    # one f32 ulp of these activations apart.
+    if prec == "int8":
+        np.testing.assert_array_equal(outs["jit"], outs["pallas"])
+    else:
+        np.testing.assert_allclose(outs["jit"], outs["pallas"], atol=1e-6)
     # the eager oracle differs only by XLA's jit-side fma contraction
     # of the dequant/bias chain (~1 ulp of the activations)
     np.testing.assert_allclose(outs["interpret"], outs["jit"], atol=1e-5)
@@ -134,12 +141,11 @@ def test_int8_error_within_default_budget(rng):
     assert float(np.abs(want - got).max()) <= 0.05
 
 
-def test_backend_prior_conv_stays_f32_off_tpu():
+def test_backend_prior_conv_stays_f32_off_tpu(monkeypatch):
     """Off-TPU, int8 annotates dense sites only (XLA CPU int8 conv is a
     slowdown); bf16 annotates both."""
     import jax
-    if any(d.platform == "tpu" for d in jax.devices()):
-        pytest.skip("prior under test is the CPU one")
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     g, _ = _cnn()
     q8 = _quantized_graph(g, mode="int8")
     modes8 = {n.op: n.attrs.get("quant.mode") for n in q8.nodes

@@ -60,7 +60,11 @@ _SPMD_SCRIPT = textwrap.dedent("""
         make_jitted_train_step
     from repro.distributed import sharding as shd
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    # Auto axes: the sharding rules place tensors through
+    # with_sharding_constraint, which Explicit axes (make_mesh's
+    # default) refuse.
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config("{arch}", smoke=True)
     m = get_model(cfg)
     tc = TrainConfig(opt=OptConfig(lr=1e-3, total_steps=4,
