@@ -21,6 +21,7 @@ import numpy as np
 import jax
 
 import repro
+from repro.api.cache import compilation_cache_events, use_compilation_cache
 
 from .table1_models import SUITE
 
@@ -86,11 +87,14 @@ def run(reps: int = 20,
             lambda x=x: oracle(**{in_name: x})[out_name],
             reps=max(3, reps // 4))
 
-        exe = repro.compile(g, repro.CompileOptions(target="jit"))
-        # Time the raw specialized program (as the paper does), not the
-        # Executable's per-call Python veneer — on sub-ms models the
-        # dict plumbing would dominate the measurement.
-        fn = exe.ensure_compiled(batch_size=1)
+        # A compile served from JAX's persistent cache is a load, not a
+        # compile: the row counts the cache's hits and misses with it.
+        with compilation_cache_events() as cache:
+            exe = repro.compile(g, repro.CompileOptions(target="jit"))
+            # Time the raw specialized program (as the paper does), not
+            # the Executable's per-call Python veneer — on sub-ms models
+            # the dict plumbing would dominate the measurement.
+            fn = exe.ensure_compiled(batch_size=1)
         t_compiled = _time_call(lambda x=x: fn(x), reps=reps)
 
         # numerics vs oracle (the paper's SimpleNN role)
@@ -103,6 +107,8 @@ def run(reps: int = 20,
             "compiled_ms": t_compiled * 1e3,
             "speedup": t_simple / t_compiled,
             "compile_time_ms": (exe.compile_time or 0) * 1e3,
+            "compile_cache_hits": cache["hits"],
+            "compile_cache_misses": cache["misses"],
             "max_abs_err": err,
         }
 
@@ -190,7 +196,6 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "artifact (the CI perf-trajectory format)")
     args = ap.parse_args(argv)
 
-    from repro.api.cache import use_compilation_cache
     use_compilation_cache()
 
     rows = run(reps=args.reps, configs=args.configs,
@@ -198,7 +203,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                autotune_budget_ms=args.autotune_budget_ms,
                precision=args.precision, calibrate=args.calibrate)
     hdr = f"{'model':<12} {'interp ms':>10} {'compiled ms':>12} " \
-          f"{'speedup':>8} {'compile ms':>11} {'max err':>9}"
+          f"{'speedup':>8} {'compile ms':>11} {'cache h/m':>9} {'max err':>9}"
     if args.autotune:
         hdr += f" {'pallas ms':>10} {'tuned ms':>9} {'tuned x':>8}"
     if args.precision:
@@ -209,7 +214,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     for name, r in rows.items():
         line = (f"{name:<12} {r['interpreted_ms']:>10.3f} "
                 f"{r['compiled_ms']:>12.3f} {r['speedup']:>8.1f} "
-                f"{r['compile_time_ms']:>11.1f} {r['max_abs_err']:>9.2e}")
+                f"{r['compile_time_ms']:>11.1f} "
+                f"{r['compile_cache_hits']:>4}/{r['compile_cache_misses']:<4} "
+                f"{r['max_abs_err']:>9.2e}")
         if args.autotune:
             line += (f" {r['pallas_heuristic_ms']:>10.3f} "
                      f"{r['pallas_autotuned_ms']:>9.3f} "

@@ -11,6 +11,8 @@ One chip:
   the ``jit`` and ``pallas`` targets at batch 1 and 8, against the
   ``interpret`` oracle; the int8 classifiers on ``pallas`` against the
   float32 oracle;
+* the decode-attention kernel at qwen2.5-14b's head layout against its
+  jnp reference (the served path does not run it);
 * the serving path: qwen2.5-14b at its published widths, cut to
   ``LAYERS`` layers, compiled for the ``engine`` target and served 8
   seeded requests by ``repro.serve``.
@@ -28,6 +30,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -173,6 +176,38 @@ def compiler_phase() -> None:
                    f"{name} int8: dense kernels {kernels}")
             _check(err <= INT8_BUDGET,
                    f"{name} int8: error {err} over {INT8_BUDGET}")
+
+
+def decode_attention_phase() -> None:
+    """``decode_attention_p`` compiled for the chip at qwen2.5-14b's
+    head layout, over a cache that pads to two tiles, with ragged
+    lengths (one row, mid-tile, a tile boundary, the full cache),
+    against the jnp reference at ``highest`` precision.  The exact exp
+    only: the Schraudolph body is not multiplicative, so its tiled
+    online softmax departs from a one-pass reference by design."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.kernels.decode_attention.kernel import (DEFAULT_BS,
+                                                       decode_attention_p)
+    from repro.kernels.decode_attention.ref import decode_attention_ref
+
+    b, h, hkv, d, s = 4, 40, 8, 128, DEFAULT_BS + 300
+    lengths = jnp.asarray([1, 97, DEFAULT_BS, s], jnp.int32)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(SEED), 3)
+    q = jax.random.normal(kq, (b, h, d), jnp.float32)
+    k = jax.random.normal(kk, (b, s, hkv, d), jnp.float32)
+    v = jax.random.normal(kv, (b, s, hkv, d), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = decode_attention_ref(q, k, v, lengths)
+        got = jax.jit(functools.partial(decode_attention_p, interpret=False))(
+            q, k, v, lengths)
+    err, scale = _max_err({"out": want}, {"out": got})
+    tol = TABLE1_RTOL["highest"] * scale
+    _say(f"[decode_attention] B={b} H={h} Hkv={hkv} D={d} S={s} "
+         f"lengths={np.asarray(lengths).tolist()} "
+         f"max_abs_err={err!r} tol={tol!r}")
+    _check(err <= tol, f"decode_attention: error {err} over {tol}")
 
 
 # ---------------------------------------------------------------------------
@@ -337,6 +372,9 @@ def main(argv=None) -> int:
     # bundle or IR dump that the environment points at.
     for var in ("REPRO_CACHE_DIR", "REPRO_CAPTURE_DIR", "REPRO_DUMP_IR"):
         os.environ.pop(var, None)
+    # The TPU library reads this when it loads; unset, it writes its
+    # logs to a fixed directory outside the checkout.
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
     import jax
     dev = jax.devices()[0]
@@ -345,16 +383,8 @@ def main(argv=None) -> int:
               f"{dev.platform!r}", file=sys.stderr)
         return 1
     from importlib.metadata import version
-    from repro.api.cache import use_compilation_cache
+    from repro.api.cache import compilation_cache_events, use_compilation_cache
     cache_dir = use_compilation_cache()
-    hits = {"/jax/compilation_cache/cache_hits": 0,
-            "/jax/compilation_cache/cache_misses": 0}
-
-    def count(event, **_):
-        if event in hits:
-            hits[event] += 1
-
-    jax.monitoring.register_event_listener(count)
     _say(f"[device] platform={dev.platform} kind={dev.device_kind} "
          f"count={len(jax.devices())} jax={jax.__version__} "
          f"libtpu={version('libtpu')} compilation_cache={cache_dir}")
@@ -362,14 +392,15 @@ def main(argv=None) -> int:
     _check(len(jax.devices()) == args.chips,
            f"--chips {args.chips}: JAX sees {len(jax.devices())} devices")
     t0 = time.perf_counter()
-    if args.chips == 1:
-        compiler_phase()
-        serving_phase()
-    else:
-        multichip_phase(args.chips)
-    _say(f"[cache] persistent compilation cache "
-         f"hits={hits['/jax/compilation_cache/cache_hits']} "
-         f"misses={hits['/jax/compilation_cache/cache_misses']}; "
+    with compilation_cache_events() as hits:
+        if args.chips == 1:
+            compiler_phase()
+            decode_attention_phase()
+            serving_phase()
+        else:
+            multichip_phase(args.chips)
+    _say(f"[cache] persistent compilation cache hits={hits['hits']} "
+         f"misses={hits['misses']}; "
          f"wall {time.perf_counter() - t0:.1f}s (not a speed result)")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -17,6 +17,7 @@ point ``cache_dir``/``$REPRO_CACHE_DIR`` only at directories you own.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import os
 import pathlib
@@ -69,6 +70,26 @@ def use_compilation_cache() -> str:
     return COMPILATION_CACHE_DIR
 
 
+@contextlib.contextmanager
+def compilation_cache_events():
+    """Count the persistent compilation cache's hits and misses inside
+    the block; yields ``{"hits": n, "misses": n}``, filled as it runs.
+    A compile time measured over a hit is a cache load, not a compile."""
+    names = {"/jax/compilation_cache/cache_hits": "hits",
+             "/jax/compilation_cache/cache_misses": "misses"}
+    counts = {"hits": 0, "misses": 0}
+
+    def listen(event, **_):
+        if event in names:
+            counts[names[event]] += 1
+
+    jax.monitoring.register_event_listener(listen)
+    try:
+        yield counts
+    finally:
+        jax.monitoring.unregister_event_listener(listen)
+
+
 def resolve_cache_dir(explicit: Optional[str]) -> Optional[str]:
     """Explicit option wins; else ``$REPRO_CACHE_DIR``; else disabled."""
     return explicit if explicit is not None else os.environ.get("REPRO_CACHE_DIR")
@@ -86,12 +107,12 @@ class ExecutableCache:
     def _path(self, key: str) -> str:
         return os.path.join(self.root, f"{key}.xla")
 
-    def load(self, key: str, devices=None):
+    def load(self, key: str, devices):
         """Return a loaded executable, or None on miss/failure.
 
-        ``devices`` are the devices the executable runs on, in its
-        device-assignment order (a sharded program's mesh); ``None``
-        loads onto every local device, right for a single-device one.
+        ``devices`` are the devices the executable was compiled for, in
+        its device-assignment order (one for an unsharded program, the
+        mesh's for a sharded one).
         """
         path = self._path(key)
         if not os.path.exists(path):

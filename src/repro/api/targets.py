@@ -325,9 +325,13 @@ class JitExecutable(GraphExecutable):
         return fn
 
     def _execution_devices(self):
-        """Devices a cached program is loaded onto, in assignment
-        order (None = the default device, the unsharded case)."""
-        return None
+        """Devices the program is compiled for, in assignment order; a
+        cached copy is loaded onto these.  Unsharded: the default
+        device."""
+        dev = jax.config.jax_default_device
+        if dev is None or isinstance(dev, str):
+            dev = jax.devices(dev)[0]
+        return [dev]
 
     # -- compilation ---------------------------------------------------
     def _resolve_selection(self, batch_size: int, *,

@@ -355,8 +355,10 @@ def tune_graph_decisions(
     graph_budget = (budget_ms * GRAPH_BUDGET_FRACTION
                     if (mode == "full" and budget_ms is not None) else
                     (None if mode == "full" else 0.0))
-    deadline = Deadline(graph_budget)
+    # Fingerprinting reads the backend, which brings it up on a first
+    # call: set-up, not measurement, so it runs before the clock starts.
     fingerprint = environment_fingerprint()
+    deadline = Deadline(graph_budget)
     decided = graph.copy()
     pipeline: Optional[Tuple[str, ...]] = (tuple(passes)
                                            if passes is not None else None)

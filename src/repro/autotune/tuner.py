@@ -103,8 +103,8 @@ def tune_selection(
     if mode not in ("cached", "full"):
         raise ValueError(f"autotune mode must be 'cached' or 'full' here, "
                          f"got {mode!r}")
+    fingerprint = environment_fingerprint()   # may bring the backend up
     deadline = Deadline(budget_ms if mode == "full" else None)
-    fingerprint = environment_fingerprint()
     memo: Dict[str, dict] = {}
     tuned: Dict[str, KernelChoice] = dict(selection)
     measured_nodes, cached_nodes, heuristic_nodes = [], [], []

@@ -253,13 +253,13 @@ def _tune_mixed(nodes: List[Node], graph: Graph, ranges: Dict,
 
     budget = req.get("budget") or DEFAULT_PRECISION_BUDGET
     measure = req.get("measure", True)
+    fp = environment_fingerprint()   # may bring the backend up
     deadline = Deadline(req.get("budget_ms", DEFAULT_MEASURE_BUDGET_MS)
                         if measure else 0.0)
     cache = None
     if req.get("use_cache", True):
         from ...autotune import open_tactic_cache
         cache = open_tactic_cache(req.get("cache_dir"))
-    fp = environment_fingerprint()
 
     decisions: Dict[str, str] = {}
     entries: Dict[str, dict] = {}

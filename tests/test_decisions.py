@@ -211,6 +211,26 @@ def test_tune_graph_decisions_cached_replays(tmp_path):
     assert rep2["spent_ms"] < 50            # cached mode never measures
 
 
+def test_spent_ms_excludes_fingerprint(tmp_path, monkeypatch):
+    """The fingerprint reads the backend, which a fresh process brings
+    up on that first read: set-up, not measurement spend."""
+    import time
+    from repro.autotune import decisions
+    real = decisions.environment_fingerprint
+
+    def slow():
+        time.sleep(0.2)
+        return real()
+
+    monkeypatch.setattr(decisions, "environment_fingerprint", slow)
+    _, _, rep = tune_graph_decisions(
+        _mlp(), target="pallas", precision="exact", passes=None,
+        mode="cached", budget_ms=None,
+        cache=TacticCache(os.path.join(str(tmp_path), "tactics")),
+        batch_size=1)
+    assert rep["spent_ms"] < 50
+
+
 def test_cached_mode_without_entries_keeps_heuristics(tmp_path):
     g = _mlp()
     cache = TacticCache(os.path.join(str(tmp_path), "tactics"))

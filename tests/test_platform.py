@@ -113,6 +113,17 @@ def test_compilation_cache_dir_fixed_in_checkout(monkeypatch,
     assert jax.config.jax_compilation_cache_dir == first
 
 
+def test_compilation_cache_events_counted_inside_block_only():
+    from repro.api.cache import compilation_cache_events
+    with compilation_cache_events() as counts:
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+        jax.monitoring.record_event("/jax/some_other_event")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    assert counts == {"hits": 1, "misses": 2}
+
+
 def test_chip_smoke_refuses_cpu():
     out = _run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
                cwd=ROOT)

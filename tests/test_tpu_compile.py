@@ -10,6 +10,8 @@ process at a time may load the TPU library, and the test workers all
 import this file.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -27,6 +29,9 @@ BLOCK = (256, 512, 256)
 
 @pytest.fixture(scope="module")
 def topo():
+    # The TPU library reads this when it loads; unset, it writes its
+    # compiler logs to a fixed directory outside the checkout.
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
         return topologies.get_topology_desc(platform="tpu",
