@@ -1,0 +1,13 @@
+"""Host time of ``repro.step.decode`` per decoding step: the last
+tokens placed and the decode program called, summed over the traced
+window and divided by the window's steps that decoded.  Spans under
+20 us are dropped by the trace loader and count as nothing."""
+
+from bench import program_spans
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    steps = sum(bool(s.decode_contexts) for s in run.window_steps())
+    return program_spans.mean_ms(run.trace, "repro.step.decode", steps)

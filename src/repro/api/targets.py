@@ -29,6 +29,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.graph import Graph
 from ..frontends.container import save_model
@@ -123,6 +124,11 @@ class GraphExecutable(Executable):
     def _gather_inputs(self, pos, inputs) -> List[jnp.ndarray]:
         """Bind positional-or-keyword call args against the signature;
         returns arrays ordered by the graph's declared inputs."""
+        return self._transfer_inputs(self._bind_inputs(pos, inputs))
+
+    def _bind_inputs(self, pos, inputs) -> dict:
+        """Bind the call args against the signature and check each
+        input's shape, on the host, before any transfer."""
         inputs = self.signature.bind(pos, inputs)
         missing = [n for n in self.source.inputs if n not in inputs]
         if missing:
@@ -132,15 +138,17 @@ class GraphExecutable(Executable):
         if unknown:
             raise TypeError(f"unexpected inputs {unknown}; expected "
                             f"{list(self.source.inputs)}")
-        args = []
         for n, spec in self.source.inputs.items():
-            a = jnp.asarray(inputs[n])
-            if a.shape[1:] != spec.shape:
+            shape = np.shape(inputs[n])
+            if shape[1:] != spec.shape:
                 raise ValueError(
                     f"input {n!r}: expected (batch,)+{spec.shape}, "
-                    f"got {a.shape}")
-            args.append(a)
-        return args
+                    f"got {shape}")
+        return inputs
+
+    def _transfer_inputs(self, inputs: dict) -> List[jnp.ndarray]:
+        """Bound inputs as device arrays, in the graph's input order."""
+        return [jnp.asarray(inputs[n]) for n in self.source.inputs]
 
     def _public_outputs(self, out) -> dict:
         """Re-key an output dict from graph tensor names to the
@@ -475,24 +483,32 @@ class JitExecutable(GraphExecutable):
         return batch
 
     def __call__(self, *pos, **inputs):
-        args = self._gather_inputs(pos, inputs)
-        batch = args[0].shape[0]
-        bucket = self._pick_bucket(batch)
-        fn = self.ensure_compiled(bucket)
-        if bucket != batch:
-            args = [
-                jnp.concatenate(
-                    [a, jnp.zeros((bucket - batch,) + a.shape[1:], a.dtype)])
-                for a in args
-            ]
-        out = fn(*args)
-        if bucket != batch:
-            out = {k: v[:batch] for k, v in out.items()}
-        # Passes may rename output tensors (e.g. a fused terminal
-        # activation); the public contract keys outputs by the
-        # signature's names, identically across targets.
-        return {pub: out[opt] for pub, opt in
-                zip(self.source.output_names, self.graph.outputs)}
+        # Four leaf profiler spans (none encloses another), so a trace
+        # shows which step of the call the host was in.
+        with TraceAnnotation("repro.call.bind"):
+            inputs = self._bind_inputs(pos, inputs)
+        with TraceAnnotation("repro.call.transfer"):
+            args = self._transfer_inputs(inputs)
+        with TraceAnnotation("repro.call.dispatch"):
+            batch = args[0].shape[0]
+            bucket = self._pick_bucket(batch)
+            fn = self.ensure_compiled(bucket)
+            if bucket != batch:
+                args = [
+                    jnp.concatenate(
+                        [a, jnp.zeros((bucket - batch,) + a.shape[1:],
+                                      a.dtype)])
+                    for a in args
+                ]
+            out = fn(*args)
+        with TraceAnnotation("repro.call.outputs"):
+            if bucket != batch:
+                out = {k: v[:batch] for k, v in out.items()}
+            # Passes may rename output tensors (e.g. a fused terminal
+            # activation); the public contract keys outputs by the
+            # signature's names, identically across targets.
+            return {pub: out[opt] for pub, opt in
+                    zip(self.source.output_names, self.graph.outputs)}
 
     # -- introspection -------------------------------------------------
     def cache_info(self) -> dict:

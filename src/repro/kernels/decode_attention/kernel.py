@@ -135,5 +135,6 @@ def decode_attention_p(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
         interpret=interpret,
+        name="decode_attention",
     )(lengths, qg, k_cache.reshape(kv_shape), v_cache.reshape(kv_shape))
     return out.reshape(b, h, d)

@@ -85,5 +85,6 @@ def fast_act_2d(x: jnp.ndarray, fn: str, *, interpret: bool,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), x.dtype),
         interpret=interpret,
+        name="fast_act",
     )(xp)
     return out[:m, :n]

@@ -174,6 +174,7 @@ def fused_matmul_p(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="fused_matmul",
     )(*operands)
 
 
@@ -291,5 +292,6 @@ def fused_matmul_q8_p(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="fused_matmul_q8",
     )(*operands)
 

@@ -34,6 +34,7 @@ assert exact numbers.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import re
@@ -44,6 +45,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..dist.mesh import MeshUnavailableError, ensure_mesh_available
 from .metrics import RequestMetrics, SchedulerMetrics
@@ -847,36 +849,44 @@ class Scheduler:
         """One scheduler iteration: admit into free slots, one batched
         decode step, sample + evict.  Returns the number of slots still
         active afterwards."""
+        # Each phase that runs is one leaf profiler span (none encloses
+        # another), so a trace shows which phase the host was in.
         self._check_mesh()          # no-op unless mesh-parallel serving
-        self._admit_free_slots()
-        self._advance_prefills()    # no-op unless chunked prefill is on
+        with (TraceAnnotation("repro.step.admit") if self._queue
+              else contextlib.nullcontext()):
+            self._admit_free_slots()
+        if self._prefilling:        # only with chunked prefill
+            with TraceAnnotation("repro.step.prefill"):
+                self._advance_prefills()
         active = self.slot_manager.active_slots()
         if not active:
             return 0
-        if self._decode_engine is not None:
-            logits = self._bucketed_decode(len(active))
-            active = self.slot_manager.active_slots()  # post-compaction
-        else:
-            decode = self._decode_aot or self._decode
-            logits, self.slot_manager.cache = decode(
-                self.params, self.slot_manager.cache, self._tokens())
-            logits = logits[:, 0]
+        with TraceAnnotation("repro.step.decode"):
+            if self._decode_engine is not None:
+                logits = self._bucketed_decode(len(active))
+                active = self.slot_manager.active_slots()  # post-compaction
+            else:
+                decode = self._decode_aot or self._decode
+                logits, self.slot_manager.cache = decode(
+                    self.params, self.slot_manager.cache, self._tokens())
+                logits = logits[:, 0]
         self.metrics.decode_steps += 1
         self.metrics.decode_slot_steps += len(active)
-        for slot in active:
-            st = self.slot_manager.state(slot)
-            m = self.request_metrics[st.uid]
-            tok = self.sampler(logits[slot:slot + 1], st.temperature,
-                               uid=st.uid, index=m.new_tokens)
-            self.generated[st.uid].append(tok)
-            self.last_token[slot, 0] = tok
-            m.new_tokens += 1
-            self.metrics.total_new_tokens += 1
-            st.remaining -= 1
-            if tok == st.eos_id:
-                self._retire(slot, "eos")
-            elif st.remaining <= 0:
-                self._retire(slot, "length")
+        with TraceAnnotation("repro.step.sample"):
+            for slot in active:
+                st = self.slot_manager.state(slot)
+                m = self.request_metrics[st.uid]
+                tok = self.sampler(logits[slot:slot + 1], st.temperature,
+                                   uid=st.uid, index=m.new_tokens)
+                self.generated[st.uid].append(tok)
+                self.last_token[slot, 0] = tok
+                m.new_tokens += 1
+                self.metrics.total_new_tokens += 1
+                st.remaining -= 1
+                if tok == st.eos_id:
+                    self._retire(slot, "eos")
+                elif st.remaining <= 0:
+                    self._retire(slot, "length")
         self.metrics.last_step_at = self.clock()
         return self.slot_manager.num_active()
 

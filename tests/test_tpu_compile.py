@@ -4,7 +4,9 @@ at real widths, with no chip attached.
 Interpret mode checks a kernel's arithmetic but not what the chip's
 compiler accepts (block shapes against the (8, 128) tiling, VMEM use).
 Here each kernel is lowered and compiled for one chip of a ``v5e:2x2``
-topology and its program must hold a ``tpu_custom_call``.  The
+topology and its program must hold a ``tpu_custom_call`` under the
+kernel's own name (``pallas_call(name=...)``), which is the name a
+profiler trace gives its operations.  The
 topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all
 import this file.
@@ -74,6 +76,7 @@ def test_fused_matmul_compiles(one_chip, dtype, w_layout):
                                        block=BLOCK, interpret=False),
         one_chip, ((M, K), dtype), (w_shape, dtype), ((N,), jnp.float32))
     assert "tpu_custom_call" in text
+    assert "%fused_matmul" in text and "%fused_matmul_q8" not in text
 
 
 def test_fused_matmul_q8_compiles(one_chip):
@@ -83,6 +86,7 @@ def test_fused_matmul_q8_compiles(one_chip):
         one_chip, ((M, K), jnp.int8), ((K, N), jnp.int8),
         ((N,), jnp.float32), ((N,), jnp.float32))
     assert "tpu_custom_call" in text
+    assert "%fused_matmul_q8" in text
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -92,6 +96,7 @@ def test_fast_act_compiles(one_chip, dtype, fn):
     text = _compile(lambda x: fast_act_2d(x, fn, interpret=False),
                     one_chip, ((1024, K), dtype))
     assert "tpu_custom_call" in text
+    assert "%fast_act" in text
 
 
 @pytest.mark.parametrize("fast", [False, True], ids=["exact", "fast"])
@@ -103,3 +108,4 @@ def test_decode_attention_compiles(one_chip, fast):
         one_chip, ((b, h, d), jnp.float32), ((b, s, hkv, d), jnp.float32),
         ((b, s, hkv, d), jnp.float32), ((b,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert "%decode_attention" in text
