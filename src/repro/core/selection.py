@@ -43,7 +43,8 @@ import numpy as np
 from .graph import Graph
 from ..kernels.platform import on_tpu
 from ..kernels.tiles import (LANE, VMEM_BUDGET_BYTES, block_vmem_bytes,
-                             ceil_to, pick_block, sublane_for)
+                             ceil_to, grid_steps, pick_block, streams_weights,
+                             sublane_for)
 
 #: Padded-MACs / logical-MACs bound for the fused matmul (lane/sublane
 #: granule waste).  The table-1 suite's smallest head (32×2, batch 1)
@@ -60,7 +61,10 @@ class KernelChoice:
     prior or a micro-benchmarked winner from :mod:`repro.autotune`;
     measured choices also carry the winning ``block`` geometry (honored
     by the Pallas lowering rules instead of recomputing ``pick_block``)
-    and the per-candidate ``measured_us`` table.
+    and the per-candidate ``measured_us`` table.  A heuristic Pallas
+    dense choice records which rule of ``pick_block`` gave its block
+    (``geometry``: ``"stream"`` at few rows, ``"tile"`` otherwise) and
+    the kernel's ``grid_steps``.
     """
 
     node: str
@@ -70,6 +74,8 @@ class KernelChoice:
     source: str = "heuristic"          # "heuristic" | "measured"
     block: Optional[Tuple[int, ...]] = None
     measured_us: Optional[Dict[str, float]] = None
+    geometry: Optional[str] = None     # "stream" | "tile"
+    grid_steps: Optional[int] = None
 
     def to_dict(self) -> Dict[str, object]:
         return dataclasses.asdict(self)
@@ -117,11 +123,13 @@ def _select_dense(node, in_spec, batch_size: int, n: int) -> KernelChoice:
             f"(> {MAX_PAD_WASTE:.0f}x) at M={m} K={k} N={n}")
     kernel = ("pallas.fused_matmul_q8" if qm == "int8"
               else "pallas.fused_matmul")
+    geometry = "stream" if streams_weights(m, itemsize) else "tile"
+    steps = grid_steps(m_pad, k_pad, n_pad, (bm, bk, bn))
     return KernelChoice(
         node.name, "dense", kernel,
-        f"M={m} K={k} N={n} tiles to ({bm},{bk},{bn}), "
-        f"{vmem // 1024} KiB VMEM, {waste:.1f}x pad waste",
-        block=(bm, bk, bn))
+        f"M={m} K={k} N={n} {geometry}s to ({bm},{bk},{bn}) in {steps} "
+        f"steps, {vmem // 1024} KiB VMEM, {waste:.1f}x pad waste",
+        block=(bm, bk, bn), geometry=geometry, grid_steps=steps)
 
 
 def _select_activation(node, in_spec, precision: str) -> KernelChoice:

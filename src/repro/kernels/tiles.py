@@ -28,6 +28,26 @@ SUBLANE = 8
 #: resident tiles against it).
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
+#: Padded rows up to which :func:`pick_block` streams the weights.
+#: Each weight element is read once and used by ``m`` rows, so the
+#: kernel moves ``itemsize`` bytes for ``2·m`` operations.  On a TPU v5e
+#: (819 GB/s of HBM, 197 TF/s bf16) an f32 matmul at ``highest`` runs
+#: six bf16 MXU passes, about 33 TF/s: the weight stream bounds it
+#: while ``2·m / 4 · 819e9 < 33e12``, below about 80 rows.  64 is the
+#: largest row count under that which every dtype's sublane granule
+#: divides; bf16 and int8 stay stream-bound to higher ``m``, so the
+#: f32 bound is the one that holds for all three.
+STREAM_MAX_ROWS = 64
+#: Weight-tile bytes of the streaming geometry.  A grid step has a
+#: fixed cost of a fraction of a microsecond; a tile whose DMA takes
+#: several microseconds (4 MiB at 819 GB/s: 5.1 µs) hides it, and two
+#: such tiles, double-buffered, stay well inside the 16 MiB of VMEM.
+STREAM_TILE_BYTES = 4 * 1024 * 1024
+#: Widest N block of the streaming geometry.  Every N block re-reads
+#: the x rows, so a wide block reads them fewer times; 2048 leaves a
+#: 4 MiB f32 tile 512 deep in K.
+STREAM_BN = 2048
+
 
 def ceil_to(n: int, align: int) -> int:
     """Round ``n`` up to a multiple of ``align`` (the one copy of the
@@ -55,20 +75,55 @@ def block_vmem_bytes(bm: int, bk: int, bn: int, itemsize: int = 4,
     return itemsize * (bm * bk + bk * bn) + acc_itemsize * 2 * (bm * bn)
 
 
+def streams_weights(m: int, itemsize: int = 4) -> bool:
+    """Whether a matmul of ``m`` rows takes the weight-streaming
+    geometry of :func:`pick_block`: its padded row count is at most
+    :data:`STREAM_MAX_ROWS`."""
+    return ceil_to(m, sublane_for(itemsize)) <= STREAM_MAX_ROWS
+
+
+def _lane_divisor(total: int, cap: int) -> int:
+    """The largest multiple of :data:`LANE` that divides ``total`` (a
+    multiple of LANE) and is at most ``cap``; LANE if none is."""
+    best = LANE
+    for d in range(LANE, min(total, cap) + 1, LANE):
+        if total % d == 0:
+            best = d
+    return best
+
+
 def pick_block(m: int, k: int, n: int, itemsize: int = 4
                ) -> Tuple[int, int, int]:
-    """VMEM-aware block choice for the fused matmul: x(bm,bk) + w(bk,bn)
-    + acc/out(bm,bn) must fit well under VMEM; keep MXU-aligned.
+    """Block choice for the fused matmul, from its shape alone.
 
-    The K cap scales with the operand dtype — 512 for f32, 1024 for
-    bf16 — so narrow dtypes stream twice the reduction depth through
-    the same VMEM bytes instead of leaving the budget idle.
+    Few rows (:func:`streams_weights`): the matmul is a stream of weight
+    bytes, so one row block covers every row and the weight tile
+    ``(bk, bn)`` is as large as :data:`STREAM_TILE_BYTES` allows, with
+    ``bk`` and ``bn`` dividing the lane-padded K and N so the weight is
+    never padded per call.
+
+    Otherwise the tile geometry: x(bm,bk) + w(bk,bn) + acc/out(bm,bn)
+    well under VMEM and MXU-aligned, with a K cap that scales with the
+    operand dtype — 512 for f32, 1024 for bf16 — so narrow dtypes
+    stream twice the reduction depth through the same VMEM bytes.
     """
     sub = sublane_for(itemsize)
-    bm = min(256, ceil_to(m, sub))
-    bn = min(256, ceil_to(n, LANE))
-    bk = min(512 * 4 // max(1, itemsize), ceil_to(k, LANE))
+    m_pad, k_pad, n_pad = ceil_to(m, sub), ceil_to(k, LANE), ceil_to(n, LANE)
+    if streams_weights(m, itemsize):
+        bn = _lane_divisor(n_pad, STREAM_BN)
+        bk = _lane_divisor(k_pad, STREAM_TILE_BYTES // (bn * itemsize))
+        return m_pad, bk, bn
+    bm = min(256, m_pad)
+    bn = min(256, n_pad)
+    bk = min(512 * 4 // max(1, itemsize), k_pad)
     return bm, bk, bn
+
+
+def grid_steps(m: int, k: int, n: int, block: Tuple[int, int, int]) -> int:
+    """Grid steps of the fused matmul over an (m, k, n) problem padded
+    to ``block``: row blocks × column blocks × K chunks."""
+    bm, bk, bn = block
+    return -(-m // bm) * -(-n // bn) * -(-k // bk)
 
 
 #: Candidate caps the autotuner sweeps around :func:`pick_block`.  Small

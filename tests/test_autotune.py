@@ -16,7 +16,10 @@ from repro.autotune import (TacticCache, Tactic, candidates_for_node,
                             environment_fingerprint, open_tactic_cache,
                             tactic_key, tune_selection)
 from repro.core import ModelBuilder, select_kernels
-from repro.kernels.tiles import (block_vmem_bytes, enumerate_blocks,
+from repro.core import selection as selection_mod
+from repro.core.graph import Graph
+from repro.kernels.tiles import (LANE, STREAM_MAX_ROWS, block_vmem_bytes,
+                                 ceil_to, enumerate_blocks, grid_steps,
                                  pick_block, sublane_for,
                                  VMEM_BUDGET_BYTES)
 
@@ -41,10 +44,82 @@ def _compile_tuned(graph, cache_dir, mode="full", budget_ms=10_000):
 # tiles: dtype-parametrized geometry (satellite)
 # ---------------------------------------------------------------------------
 def test_pick_block_f32_unchanged():
-    # The f32 geometry is the pre-autotuner one, bit for bit.
+    """Above STREAM_MAX_ROWS rows the tile geometry is the
+    pre-autotuner one, bit for bit, for every dtype; few-row shapes
+    stream their weights instead, on purpose (next test), and a weight
+    that fits one lane tile is one block either way."""
     assert pick_block(1, 32, 2) == (8, 128, 128)
     assert pick_block(1000, 1000, 1000) == (256, 512, 256)
     assert pick_block(1000, 1000, 1000, itemsize=4) == (256, 512, 256)
+    assert pick_block(1000, 1000, 1000, itemsize=2) == (256, 1024, 256)
+    assert pick_block(1000, 1000, 1000, itemsize=1) == (256, 1024, 256)
+    assert pick_block(1000, 4096, 1000, itemsize=1) == (256, 2048, 256)
+    assert pick_block(256, 25088, 4096) == (256, 512, 256)
+    rows = STREAM_MAX_ROWS + 1
+    assert pick_block(rows, 25088, 4096) == (ceil_to(rows, 8), 512, 256)
+
+
+# VGG19's dense layers as (K, N): fc1, fc2, and fc3 with its 1000
+# outputs (the layout pass pads the weight to 1024 rows).
+_VGG19_DENSE = {"fc1": (25088, 4096), "fc2": (4096, 4096),
+                "fc3": (4096, 1000)}
+#: Most grid steps each layer may take on the streaming geometry (the
+#: tile geometry takes fc1 in 784 f32 steps of 512 KiB).
+_MAX_STEPS = {"fc1": 128, "fc2": 16, "fc3": 4}
+
+
+def _dense_graph(k: int, n: int, quant_mode=None) -> Graph:
+    """One dense node of a (k, n) weight.  The weight is a broadcast
+    zero, so the graph holds its shape and not its bytes."""
+    g = Graph()
+    g.add_input("input", (k,))
+    kernel = g.add_param("fc/kernel", np.broadcast_to(np.float32(0), (k, n)))
+    bias = g.add_param("fc/bias", np.zeros(n, np.float32))
+    g.add_node("dense", "fc", ["input"],
+               params={"kernel": kernel, "bias": bias})
+    if quant_mode:
+        g.nodes[0].attrs["quant.mode"] = quant_mode
+    g.outputs = ["fc:out"]
+    return g
+
+
+@pytest.mark.parametrize("itemsize", [4, 2, 1], ids=["f32", "bf16", "int8"])
+@pytest.mark.parametrize("m", [1, 32])
+@pytest.mark.parametrize("layer", sorted(_VGG19_DENSE))
+def test_pick_block_streams_few_row_weights(layer, m, itemsize, monkeypatch):
+    """At VGG19's batch 1 and 32 each dense layer streams its weight in
+    few large tiles that divide the lane-padded K and N (so no weight
+    is padded per call), double-buffered within VMEM, and the selector
+    records the streaming geometry and its grid steps."""
+    k, n = _VGG19_DENSE[layer]
+    bm, bk, bn = pick_block(m, k, n, itemsize)
+    assert ceil_to(k, LANE) % bk == 0 and ceil_to(n, LANE) % bn == 0
+    assert bm == ceil_to(m, sublane_for(itemsize))
+    # both operand tiles and the output double-buffered, plus the
+    # accumulator: at most twice the single-buffered working set
+    assert 2 * block_vmem_bytes(bm, bk, bn, itemsize) <= VMEM_BUDGET_BYTES
+    steps = grid_steps(bm, ceil_to(k, LANE), ceil_to(n, LANE), (bm, bk, bn))
+    assert steps <= _MAX_STEPS[layer]
+
+    # int8 only takes the Pallas kernel on a TPU; steer the selector's
+    # backend probe so its record is the chip's.
+    monkeypatch.setattr(selection_mod, "on_tpu", lambda: True)
+    mode = {4: None, 2: "bf16", 1: "int8"}[itemsize]
+    choice = select_kernels(_dense_graph(k, n, mode), batch_size=m,
+                            target="pallas")["fc"]
+    assert choice.kernel.startswith("pallas.fused_matmul")
+    assert choice.geometry == "stream"
+    assert choice.block == (bm, bk, bn)
+    assert choice.grid_steps == steps
+    assert choice.to_dict()["geometry"] == "stream"
+
+
+def test_selection_records_tile_geometry_at_many_rows():
+    choice = select_kernels(_dense_graph(4096, 4096), batch_size=256,
+                            target="pallas")["fc"]
+    assert choice.geometry == "tile"
+    assert choice.block == (256, 512, 256)
+    assert choice.grid_steps == 16 * 8
 
 
 def test_pick_block_bf16_uses_freed_budget():

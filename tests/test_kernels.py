@@ -11,6 +11,7 @@ from repro.kernels.fast_act import ref as fa_ref
 from repro.kernels.fast_act.ops import fast_act, fast_softmax
 from repro.kernels.fused_matmul import ref as fm_ref
 from repro.kernels.fused_matmul.ops import fused_matmul
+from repro.kernels.tiles import LANE, ceil_to, pick_block, streams_weights
 from repro.kernels.decode_attention import ref as da_ref
 from repro.kernels.decode_attention.ops import decode_attention
 
@@ -81,6 +82,72 @@ def test_fused_matmul_bf16_operands_match_upcast(rng):
     assert got.dtype == jnp.float32
     bound = k * np.finfo(np.float32).eps * (np.abs(x32) @ np.abs(w32))
     assert np.all(np.abs(np.asarray(want) - np.asarray(got)) <= bound)
+
+
+@pytest.mark.parametrize("m,k,n,nk", [(1, 16384, 256, 4), (5, 2048, 384, 1)],
+                         ids=["nk4", "nk1"])
+@pytest.mark.parametrize("w_layout", ["io", "oi"])
+def test_fused_matmul_streaming_geometry(m, k, n, nk, w_layout, rng):
+    """Few rows take the weight-streaming block of ``pick_block``: one
+    row block and weight tiles of megabytes, over several K chunks or
+    one; the result is the reference's to f32 summation order."""
+    assert streams_weights(m)
+    bm, bk, bn = pick_block(m, k, n)
+    assert (bm, ceil_to(k, LANE) // bk) == (8, nk)
+    x = rng.standard_normal((m, k)).astype(np.float32)
+    w = (rng.standard_normal((k, n) if w_layout == "io" else (n, k))
+         / np.sqrt(k)).astype(np.float32)
+    b = rng.standard_normal(n).astype(np.float32) * 0.1
+    want = fm_ref.fused_matmul_ref(x, w, b, None, None, fn="relu", fast=False,
+                                   w_layout=w_layout, attrs={})
+    got = fused_matmul(x, w, b, fn="relu", w_layout=w_layout, use_pallas=True)
+    np.testing.assert_allclose(np.asarray(want), np.asarray(got),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_dense_weight_not_padded_per_call():
+    """VGG19's first dense layer at batch 1 on the pallas target, with
+    the weights as arguments of the program (``embed_weights=False``):
+    the weight reaches the kernel as it is, never padded or copied into
+    another shape on each call.  The program is lowered as
+    ``JitExecutable`` lowers it, from shapes alone, with the weight in
+    the ``oi`` layout the layout pass gives a batch-1 dense node."""
+    import re
+    from repro.core import select_kernels
+    from repro.core.graph import Graph
+    from repro.core.lowering import execute_graph
+
+    k, n = 25088, 4096
+    g = Graph()
+    g.add_input("input", (k,))
+    kernel = g.add_param("fc/kernel", np.broadcast_to(np.float32(0), (n, k)))
+    bias = g.add_param("fc/bias", np.zeros(n, np.float32))
+    g.add_node("dense", "fc", ["input"], attrs={"kernel_layout": "oi"},
+               params={"kernel": kernel, "bias": bias})
+    g.outputs = ["fc:out"]
+    selection = select_kernels(g, batch_size=1, target="pallas")
+    assert selection["fc"].geometry == "stream"
+    params = {name: jax.ShapeDtypeStruct(v.shape, jnp.float32)
+              for name, v in g.params.items()}
+
+    def program(p, x):
+        return execute_graph(g, {"input": x}, p, target="pallas",
+                             batch_size=1, selection=selection)
+
+    text = jax.jit(program).lower(
+        params, jax.ShapeDtypeStruct((1, k), jnp.float32)).as_text("hlo")
+    big = []
+    for line in text.splitlines():
+        found = re.search(r"= f32\[([\d,]+)\]\S* ([\w-]+)\(", line)
+        if found:
+            dims = tuple(int(d) for d in found.group(1).split(","))
+            if np.prod(dims) >= n * k:
+                big.append((dims, found.group(2)))
+    assert ((n, k), "parameter") in big
+    # weight-sized values are the parameter and the kernel's own view of
+    # it: never another shape, never a pad, copy or transpose
+    assert {dims for dims, _ in big} == {(n, k)}, big
+    assert not {op for _, op in big} & {"pad", "copy", "transpose"}, big
 
 
 # ---------------------------------------------------------------------------

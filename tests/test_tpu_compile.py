@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.decode_attention.kernel import decode_attention_p
 from repro.kernels.fast_act.kernel import fast_act_2d
 from repro.kernels.fused_matmul.kernel import fused_matmul_p, fused_matmul_q8_p
+from repro.kernels.tiles import STREAM_MAX_ROWS, pick_block
 
 # qwen2.5-14b widths: the FFN matmul, and decode attention over 40 query
 # and 8 KV heads of 128 lanes against a 4096-token cache.
@@ -77,6 +78,25 @@ def test_fused_matmul_compiles(one_chip, dtype, w_layout):
         one_chip, ((M, K), dtype), (w_shape, dtype), ((N,), jnp.float32))
     assert "tpu_custom_call" in text
     assert "%fused_matmul" in text and "%fused_matmul_q8" not in text
+
+
+@pytest.mark.parametrize("m", [8, STREAM_MAX_ROWS])
+def test_fused_matmul_streaming_block_compiles(one_chip, m):
+    """VGG19's first dense layer (K 25088, N 4096, weight ``oi``) at few
+    rows, with the weight-streaming block ``pick_block`` gives it, at
+    ``highest`` precision as the benchmark runs it: the chip's compiler
+    accepts the multi-megabyte tiles, their six-pass temporaries and
+    the double buffers within the default scoped VMEM."""
+    k, n = 25088, 4096
+    block = pick_block(m, k, n)
+    with jax.default_matmul_precision("highest"):
+        text = _compile(
+            lambda x, w, b: fused_matmul_p(x, w, b, fn="relu", w_layout="oi",
+                                           block=block, interpret=False),
+            one_chip, ((m, k), jnp.float32), ((n, k), jnp.float32),
+            ((n,), jnp.float32))
+    assert text.count("tpu_custom_call") == 1
+    assert "%fused_matmul" in text
 
 
 def test_fused_matmul_q8_compiles(one_chip):
