@@ -9,7 +9,8 @@ in the program's place (the upper reading).
 
 Each seed is a short run of the cell through its runner, at the cell's
 own sizes and load, all in one process; the results go to standard
-output as one JSON line per seed.  The benchmark's own runs never run
+output as one JSON line per seed, with ``correct`` for the program and
+``control_correct`` for the control, each by the run's own comparison.  The benchmark's own runs never run
 the control.
 """
 
@@ -23,6 +24,21 @@ import time
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
+
+
+def readings(rec) -> dict:
+    """The compared number of the program and of the control on the
+    same requests or inputs, each judged by the run's own comparison
+    (``record.Run.check``) against the configuration's limit."""
+    from bench import record
+    (chk,) = rec.checks
+    control = rec.control_reading()
+    judge = record.Run(rec.config, rec.traffic)
+    return {"number": chk["name"], "program": chk["value"],
+            "control": control, "limit": chk["limit"],
+            "correct": rec.correct,
+            "control_correct": judge.check(chk["name"], control,
+                                           chk["limit"])}
 
 
 def main(argv=None) -> int:
@@ -49,12 +65,8 @@ def main(argv=None) -> int:
         rec = runner.run(cell, cell.config_module(), seed=seed,
                          seconds=args.seconds, trace_dir=None, t_start=t,
                          watch=watch, say=say)
-        (chk,) = rec.checks
         print(json.dumps({"workload": cell.name, "seed": seed,
-                          "number": chk["name"], "program": chk["value"],
-                          "control": rec.control_reading(),
-                          "limit": chk["limit"], "correct": rec.correct,
-                          "attempted": rec.attempted,
+                          **readings(rec), "attempted": rec.attempted,
                           "failed": rec.failed}), flush=True)
         del rec
     return 0

@@ -199,6 +199,9 @@ def run(cell, cfgmod, *, seed, seconds, trace_dir, t_start, watch, say,
     warm(loop, cell, seed, say, watch)
 
     loop.rec = rec
+    held = max(int((d.memory_stats() or {}).get("bytes_in_use", 0))
+               for d in jax.devices()[:cell.chips])
+    say(f"[memory] bytes_in_use at window start={held}")
     arrivals = traffic.open_loop(mix, seed, seconds, cfg["vocab_size"])
     before, rt_before = watch.snapshot(), _runtime(sched)
     if trace_dir:

@@ -92,3 +92,20 @@ def test_serving_control_reads_above_the_program():
     run = tiny.drive(cell, seed=11, seconds=0.5)
     program = run.checks[0]["value"]
     assert run.control_reading() > program
+
+
+def test_serving_control_fails_the_comparison():
+    """``bench/control.py`` judges the control in the program's place by
+    the run's own comparison: it comes out not correct where the program
+    comes out correct.  At this width the int8 control does not separate
+    from the program at the cell's limit (both read 0.003-0.09 here), so
+    the limit is set at 0.02, between this seed's readings: the program
+    0.0034, the control 0.0865."""
+    from bench import control
+    chk = dict(tiny._read("bench/configs/qwen2.5-14b.json")["check"],
+               limit=0.02)
+    cell = tiny.cell("qwen14b.chat", dict(tiny.TINY_LM, check=chk),
+                     tiny.TINY_CHAT)
+    got = control.readings(tiny.drive(cell, seed=9, seconds=0.5))
+    assert got["correct"] is True and got["program"] <= got["limit"]
+    assert got["control_correct"] is False, got

@@ -4,6 +4,7 @@ runners with the look for a chip skipped."""
 from __future__ import annotations
 
 import copy
+import dataclasses
 import json
 import os
 import sys
@@ -38,6 +39,28 @@ TINY_CHAT = {"rate_per_s": 50.0,
                                "sigma": 0.5, "min": 2, "max": 16},
              "warmup": {"burst_output_tokens": 2, "seconds": 0.2},
              "drain_s": 30}
+
+
+#: CPU-sized stand-ins by the configuration's ``runner``: configuration
+#: and traffic keys that replace the listed cell's
+STAND_INS = {"compile": (TINY_CNN, {"pool": 2}),
+             "serve": (TINY_LM, TINY_CHAT)}
+#: inputs a closed-loop call keeps at most at a CPU size
+MAX_BATCH = 4
+
+
+def shrink(listed: spec.Cell) -> spec.Cell:
+    """A cell resolved from ``BENCHMARK.json`` at a CPU size: its
+    configuration's runner picks the stand-in, so a cell listed later
+    needs no edit here."""
+    config_over, traffic_over = STAND_INS[listed.config["runner"]]
+    config = copy.deepcopy(listed.config)
+    config.update(copy.deepcopy(config_over))
+    traffic = copy.deepcopy(listed.traffic)
+    traffic.update(copy.deepcopy(traffic_over))
+    if traffic["loop"] == "closed":
+        traffic["batch"] = min(traffic["batch"], MAX_BATCH)
+    return dataclasses.replace(listed, config=config, traffic=traffic)
 
 
 #: (configuration file, traffic mix) of the cells the tests drive
