@@ -65,6 +65,11 @@ def serve(executable, options: Optional[SchedulerOptions] = None, *,
     and the scheduler options leave ``mesh`` unset, the compile-time
     mesh carries over — the serving placement follows the compiled
     artifact unless explicitly overridden.
+
+    With ``fold`` on (the default) the scheduler takes the executable's
+    weights over: the norm fold replaces each matrix in place (the
+    originals are deleted, for every holder), and ``executable.params``
+    becomes the folded weights.
     """
     model = getattr(executable, "model", None)
     params = getattr(executable, "params", None)
@@ -90,4 +95,12 @@ def serve(executable, options: Optional[SchedulerOptions] = None, *,
     info = _precision_report(executable)
     if info is not None:
         extra["precision_info"] = info
-    return Scheduler(model, params, options, sampler=sampler, **extra)
+    # The scheduler takes the executable's weights: the norm fold writes
+    # each folded matrix into its original's buffer, so set-up never
+    # holds two copies, and the executable keeps the folded weights
+    # (the same function, up to rounding).
+    sched = Scheduler(model, params, options, sampler=sampler,
+                      donate_params=options.fold, **extra)
+    if options.fold:
+        executable.params = sched.params
+    return sched

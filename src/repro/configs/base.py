@@ -30,6 +30,13 @@ class ArchConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # YaRN rotary scaling (deepseek-v3); factor 0 = plain rotary
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     attn_scale: Optional[float] = None
     attn_softcap: float = 0.0
     logit_softcap: float = 0.0
@@ -43,6 +50,12 @@ class ArchConfig:
     router_fn: str = "softmax"     # softmax | sigmoid
     moe_cf: float = 1.25           # capacity factor
     moe_aux_alpha: float = 0.01
+    n_group: int = 1               # expert groups; routing keeps the
+    topk_group: int = 1            #   topk_group best (deepseek-v3)
+    routed_scale: float = 1.0      # routed-expert weights' multiplier
+    first_k_dense: int = 0         # leading layers with a dense d_ff MLP
+    n_held_experts: int = 0        # experts held here (0 = all n_experts),
+    held_offset: int = 0           #   global ids [offset, offset + held)
     # MLA (deepseek-v3)
     mla: bool = False
     q_lora_rank: int = 0
@@ -91,6 +104,24 @@ class ArchConfig:
         if self.head_dim == 0 and self.n_heads:
             object.__setattr__(self, "head_dim",
                                self.d_model // max(1, self.n_heads))
+        if self.n_experts and self.n_experts % self.n_group:
+            raise ValueError(f"{self.name}: n_experts={self.n_experts} "
+                             f"not divisible by n_group={self.n_group}")
+        held = self.n_held_experts or self.n_experts
+        if self.held_offset + held > self.n_experts:
+            raise ValueError(f"{self.name}: held experts [{self.held_offset}"
+                             f", {self.held_offset + held}) outside "
+                             f"{self.n_experts}")
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.n_held_experts or self.n_experts
+
+    @property
+    def moe_layers(self) -> int:
+        """Layers with an expert FFN (those after the dense prefix)."""
+        return self.num_layers - self.first_k_dense if self.n_experts else 0
 
     # convenience ------------------------------------------------------
     def param_count(self) -> int:
@@ -107,8 +138,8 @@ class ArchConfig:
         if not self.n_experts:
             return total
         per_expert = 3 * self.d_model * self.moe_d_ff
-        inactive = (self.n_experts - self.top_k) * per_expert \
-            * self.num_layers
+        inactive = max(0, self.held_experts - self.top_k) * per_expert \
+            * self.moe_layers
         return total - inactive
 
 

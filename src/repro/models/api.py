@@ -11,6 +11,7 @@ dry-run all speak only this protocol:
     cache_axes()              -> logical axes for the cache
     prefill(params, batch, cache) -> (logits, cache)
     decode_step(params, cache, tokens) -> (logits, cache)
+    prefill_chunk(params, tokens, cache, start, length) -> (logits, cache)
     loss(params, batch)       -> scalar loss               [train]
 """
 
@@ -85,30 +86,37 @@ class Model:
         return self.mod.prefill(self.cfg, params, batch["tokens"], cache,
                                 **kw)
 
-    def decode_step(self, params, cache, tokens):
-        return self.mod.decode_step(self.cfg, params, cache, tokens)
+    def decode_step(self, params, cache, tokens, **kw):
+        """One decode step; ``stats=True`` (models with
+        :attr:`counts_experts`) appends the expert counters."""
+        return self.mod.decode_step(self.cfg, params, cache, tokens, **kw)
+
+    @property
+    def counts_experts(self) -> bool:
+        """True when ``decode_step``/``prefill_chunk`` can return expert
+        counters (``stats=True``): transformer-family models with
+        routed experts."""
+        return bool(self.cfg.n_experts) and self.mod is transformer
 
     def supports_chunked_prefill(self) -> bool:
         """True when the family implements incremental ``prefill_chunk``.
 
-        Transformer-family models (dense/moe) qualify unless they use an
-        MLA latent cache or need extra prefill inputs (vlm patches,
+        Transformer-family models (dense/moe, MLA latent caches too)
+        qualify unless they need extra prefill inputs (vlm patches,
         audio frames).  Callers must additionally check that the cache
         is full-context (not a ring) — see ``repro.serve``.
         """
         if not hasattr(self.mod, "prefill_chunk"):
             return False
-        if self.cfg.mla:
-            return False
         if self.cfg.family in ("vlm", "audio"):
             return False
         return True
 
-    def prefill_chunk(self, params, tokens, cache, start, length):
+    def prefill_chunk(self, params, tokens, cache, start, length, **kw):
         """Prefill one fixed-size chunk of a prompt at absolute offset
         ``start`` (see ``transformer.prefill_chunk``)."""
         return self.mod.prefill_chunk(self.cfg, params, tokens, cache,
-                                      start, length)
+                                      start, length, **kw)
 
 
 def get_model(cfg: ArchConfig) -> Model:

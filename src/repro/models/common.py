@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -70,13 +71,57 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1·mscale·ln(factor) + 1 (1 when
+    the context is not stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, original_len: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies (arXiv:2309.00071, as DeepSeek-V3's
+    ``DeepseekV3YarnRotaryEmbedding``): dimensions that turn fewer than
+    ``beta_slow`` times over the original context are interpolated by
+    ``factor``, those that turn more than ``beta_fast`` times keep their
+    frequency, with a linear ramp between."""
+    def turn_dim(turns):
+        return (dim * math.log(original_len / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(turn_dim(beta_fast)), 0)
+    high = min(math.ceil(turn_dim(beta_slow)), dim - 1)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (extra / factor * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_table(cfg, dim: int):
+    """Inverse frequencies and cos/sin amplitude of a config's rotary
+    embedding over ``dim`` dimensions: plain, or YaRN when
+    ``cfg.yarn_factor`` is set."""
+    if not cfg.yarn_factor:
+        return rope_freqs(dim, cfg.rope_theta), 1.0
+    inv = yarn_freqs(dim, cfg.rope_theta, cfg.yarn_factor,
+                     cfg.yarn_original_len, cfg.yarn_beta_fast,
+                     cfg.yarn_beta_slow)
+    amp = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+           / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return jnp.asarray(inv), amp
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               table=None) -> jnp.ndarray:
+    """x: (..., S, H, D); positions: broadcastable to (..., S).
+    ``table`` is a :func:`rope_table` (inverse frequencies, amplitude);
+    by default the plain frequencies of ``theta``."""
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)                      # (d/2,)
+    inv, amp = table if table is not None else (rope_freqs(d, theta), 1.0)
     ang = positions.astype(jnp.float32)[..., None] * inv  # (..., S, d/2)
     cos = jnp.cos(ang)[..., None, :]                # (..., S, 1, d/2)
     sin = jnp.sin(ang)[..., None, :]
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     # cast each half before the concat so every tensor that survives the
     # op (and any GSPMD reshard of it) is already in the compute dtype —

@@ -24,7 +24,16 @@ Two sharding modes, chosen at compile time from (E, n_model):
 
 Capacity-based token dropping (capacity factor ``cfg.moe_cf``) keeps
 every shape static — the paper's "statically known properties"
-requirement in MoE form.
+requirement in MoE form.  Training takes that path.
+
+Serving on one device takes the dropless path instead (``moe_serve``):
+the token-choices that fall on the experts held here
+(``cfg.held_offset`` onward, ``cfg.held_experts`` of them) are sorted
+by expert into the grouped matmul's tiled layout and run through
+``kernels/grouped_matmul`` — every choice computed, no capacity, and
+no weight bytes read for an expert no token chose.  What goes on is
+the held experts' part of the routed result plus the shared expert:
+the share one device of an expert-parallel deployment computes.
 """
 
 from __future__ import annotations
@@ -44,12 +53,15 @@ def moe_init(key, cfg):
     init = lambda k, shape, fan: (
         jax.random.normal(k, shape, jnp.float32) / jnp.sqrt(fan)
     ).astype(cfg.param_dtype)
+    eh = cfg.held_experts
     p = {
         "router": init(ks[0], (d, e), d),
-        "wi_gate": init(ks[1], (e, d, f), d),
-        "wi_up": init(ks[2], (e, d, f), d),
-        "wo": init(ks[3], (e, f, d), f),
+        "wi_gate": init(ks[1], (eh, d, f), d),
+        "wi_up": init(ks[2], (eh, d, f), d),
+        "wo": init(ks[3], (eh, f, d), f),
     }
+    if cfg.router_fn == "sigmoid":        # noaux_tc's selection bias
+        p["router_bias"] = jnp.zeros((e,), jnp.float32)
     if cfg.n_shared:
         fs = f * cfg.n_shared
         p["shared"] = {
@@ -76,6 +88,8 @@ def moe_axes(cfg):
              "wi_gate": (None, "fsdp", "mlp"),
              "wi_up": (None, "fsdp", "mlp"),
              "wo": (None, "mlp", "fsdp")}
+    if cfg.router_fn == "sigmoid":
+        p["router_bias"] = (None,)
     if cfg.n_shared:
         p["shared"] = {"wi_gate": ("fsdp", "mlp"), "wi_up": ("fsdp", "mlp"),
                        "wo": ("mlp", "fsdp")}
@@ -83,8 +97,15 @@ def moe_axes(cfg):
 
 
 # ---------------------------------------------------------------------------
-def _route(cfg, x, router):
-    """Top-k routing.  x: (T, D) -> idx (T,k), weights (T,k), aux loss."""
+def _route(cfg, x, router, bias=None):
+    """Top-k routing.  x: (T, D) -> idx (T,k), weights (T,k), aux loss.
+
+    The sigmoid router is DeepSeek-V3's ``noaux_tc``: ``bias`` (zeros
+    when None) is added to the scores for the choice only; with
+    ``n_group`` groups each group scores the sum of its two best, the
+    ``topk_group`` best groups are kept and the top-k experts are chosen
+    inside them; the chosen unbiased scores are normalized and scaled by
+    ``routed_scale``."""
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                         router.astype(jnp.float32))
     if cfg.router_fn == "softmax":            # mixtral
@@ -93,8 +114,21 @@ def _route(cfg, x, router):
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     else:                                      # deepseek-v3 sigmoid router
         scores = jax.nn.sigmoid(logits)
-        w, idx = jax.lax.top_k(scores, cfg.top_k)
+        choice = scores if bias is None else scores + bias.astype(
+            jnp.float32)
+        if cfg.n_group > 1:
+            t, e = choice.shape
+            grouped = choice.reshape(t, cfg.n_group, e // cfg.n_group)
+            gscore = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+            _, gidx = jax.lax.top_k(gscore, cfg.topk_group)
+            keep = jnp.any(gidx[..., None] == jnp.arange(cfg.n_group),
+                           axis=-2)                          # (T, G)
+            choice = jnp.where(keep[..., None], grouped,
+                               -jnp.inf).reshape(t, e)
+        _, idx = jax.lax.top_k(choice, cfg.top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w * cfg.routed_scale
         probs = scores / (jnp.sum(scores, axis=-1, keepdims=True) + 1e-20)
     e = cfg.n_experts
     sel = jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32)
@@ -127,7 +161,8 @@ def _expert_ffn(cfg, buf, wi_gate, wi_up, wo):
     return jnp.einsum("ecf,efd->ecd", h, wo.astype(buf.dtype))
 
 
-def _moe_local(cfg, x, router, wi_gate, wi_up, wo, *, e_offset, axis):
+def _moe_local(cfg, x, router, wi_gate, wi_up, wo, *, e_offset, axis,
+               bias=None):
     """Per-shard MoE.  x: (T_local, D); expert weights: the local slice
     (owning global experts [e_offset, e_offset + E_local)); psum over
     `axis` (None on a single device)."""
@@ -135,7 +170,7 @@ def _moe_local(cfg, x, router, wi_gate, wi_up, wo, *, e_offset, axis):
     e_local = wi_gate.shape[0]
     cap = max(1, int(t * cfg.top_k * cfg.moe_cf / cfg.n_experts))
 
-    idx, w, aux = _route(cfg, x, router)                 # global expert ids
+    idx, w, aux = _route(cfg, x, router, bias)           # global expert ids
     pos = _positions(cfg, idx)                           # (T, k)
 
     flat_e = idx.reshape(-1)
@@ -171,9 +206,11 @@ def moe_apply(p, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
     flat = x.reshape(b * s, d)
     mesh = current_mesh()
 
+    bias = p.get("router_bias")
     if mesh is None or "model" not in mesh.axis_names:
         y, aux = _moe_local(cfg, flat, p["router"], p["wi_gate"],
-                            p["wi_up"], p["wo"], e_offset=0, axis=None)
+                            p["wi_up"], p["wo"], e_offset=cfg.held_offset,
+                            axis=None, bias=bias)
     else:
         n_model = mesh.shape["model"]
         batch = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -188,7 +225,7 @@ def moe_apply(p, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
         def shard_fn(flat_l, router, wig, wiu, wo):
             e_off = jax.lax.axis_index("model") * e_per if ep else 0
             return _moe_local(cfg, flat_l, router, wig, wiu, wo,
-                              e_offset=e_off, axis="model")
+                              e_offset=e_off, axis="model", bias=bias)
 
         wspec = (P("model", None, None) if ep else P(None, None, "model"))
         wospec = (P("model", None, None) if ep else P(None, "model", None))
@@ -200,12 +237,87 @@ def moe_apply(p, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
         )(flat, p["router"], p["wi_gate"], p["wi_up"], p["wo"])
         aux = aux.reshape(())
 
-    y = y.reshape(b, s, d)
-    if cfg.n_shared:
-        sp = p["shared"]
-        h_g = jnp.einsum("bsd,df->bsf", x, sp["wi_gate"].astype(x.dtype))
-        h_u = jnp.einsum("bsd,df->bsf", x, sp["wi_up"].astype(x.dtype))
-        h = jax.nn.silu(h_g) * h_u
-        from .common import row_parallel_out
-        y = y + row_parallel_out(h, sp["wo"], cfg.tp_psum)
+    y = _add_shared(p, cfg, x, y.reshape(b, s, d))
     return logical(y, "batch", "seq", "embed"), aux
+
+
+def _add_shared(p, cfg, x, y):
+    """``y`` plus the shared expert's output for ``x`` (B, S, D)."""
+    if not cfg.n_shared:
+        return y
+    sp = p["shared"]
+    h_g = jnp.einsum("bsd,df->bsf", x, sp["wi_gate"].astype(x.dtype))
+    h_u = jnp.einsum("bsd,df->bsf", x, sp["wi_up"].astype(x.dtype))
+    h = jax.nn.silu(h_g) * h_u
+    from .common import row_parallel_out
+    return y + row_parallel_out(h, sp["wo"], cfg.tp_psum)
+
+
+# ---------------------------------------------------------------------------
+#: The routed experts' weights: what a serving scan may hand ``moe_serve``
+#: as the whole stack over layers.
+EXPERT_WEIGHTS = ("wi_gate", "wi_up", "wo")
+
+
+def _dropless(cfg, x, p):
+    """The held experts' part of the routed result for x (T, D), every
+    token-choice computed.  Returns (y (T, D), counts (2,) int32: the
+    token-choices on held experts, the held experts with any).  With
+    ``p["layer"]`` the expert weights are the whole stack over layers
+    and the kernel reads that layer's in place."""
+    from ..kernels.grouped_matmul import gmm_tiles, layout
+    t, d = x.shape
+    k = cfg.top_k
+    held = p["wi_gate"].shape[-3]
+    stack = p.get("layer")
+    with jax.named_scope("moe_route"):
+        idx, w, _ = _route(cfg, x, p["router"], p.get("router_bias"))
+    local = idx.reshape(-1) - cfg.held_offset               # (T*k,)
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held)     # every other expert: one bin
+    onehot = jax.nn.one_hot(group, held + 1, dtype=jnp.int32)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - 1,
+                               group[:, None], axis=1)[:, 0]
+    sizes = jnp.sum(onehot, axis=0)[:held]
+    # Each token picks an expert at most once: at most min(k, held) of
+    # its choices are held here.
+    lay = layout(sizes, t * min(k, held))
+    dest = jnp.where(mine, lay.row_start[jnp.minimum(group, held - 1)]
+                     + rank, lay.rows)
+    rows = jnp.zeros((lay.rows, d), x.dtype).at[dest].set(
+        jnp.repeat(x, k, axis=0), mode="drop")
+    h = gmm_tiles(rows, p["wi_gate"], lay, w_up=p["wi_up"], layer=stack)
+    out = gmm_tiles(h, p["wo"], lay, layer=stack)
+    # Rows of tiles past the active ones were never written: select,
+    # never multiply, so whatever they hold cannot reach the sum.
+    yk = jnp.where(mine[:, None],
+                   out[jnp.minimum(dest, lay.rows - 1)].astype(jnp.float32)
+                   * w.reshape(-1, 1), 0.0)
+    y = jnp.sum(yk.reshape(t, k, d), axis=1).astype(x.dtype)
+    counts = jnp.stack([jnp.sum(mine), jnp.sum(sizes > 0)]).astype(
+        jnp.int32)
+    return y, counts
+
+
+def moe_serve(p, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Serving form of :func:`moe_apply`: x (B, S, D) -> (out, counts).
+
+    On one device (no ``model`` mesh axis) the dropless grouped-matmul
+    path; under a model axis the expert-parallel capacity path of
+    training.  ``counts`` (2,) int32 gives the token-choices that fell
+    on the experts held here and how many of them were hit (both 0 on
+    the capacity path, which does not count them).  ``p`` may hold the
+    expert weights as the whole stack over layers with ``p["layer"]``
+    this layer's index (``transformer``'s serving scans do): the
+    grouped matmul then reads the layer's weights in place."""
+    mesh = current_mesh()
+    if mesh is not None and "model" in mesh.axis_names:
+        if "layer" in p:
+            p = {k: (v[p["layer"]] if k in EXPERT_WEIGHTS else v)
+                 for k, v in p.items() if k != "layer"}
+        y, _ = moe_apply(p, cfg, x)
+        return y, jnp.zeros((2,), jnp.int32)
+    b, s, d = x.shape
+    y, counts = _dropless(cfg, x.reshape(b * s, d), p)
+    y = _add_shared(p, cfg, x, y.reshape(b, s, d))
+    return logical(y, "batch", "seq", "embed"), counts

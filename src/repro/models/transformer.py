@@ -16,6 +16,17 @@ KV caches are ring buffers: when every layer is sliding-window
 (mixtral), the cache allocates only the window and the ring overwrite
 implements eviction; otherwise the cache covers the full context and
 local layers mask by window.  ``cache["pos"]`` counts absolute tokens.
+
+A model whose first ``cfg.first_k_dense`` layers have a dense MLP and
+the rest experts (deepseek-v3) holds two layer stacks, each scanned:
+``params["dense_layers"]`` (cache ``d1``/``d2``) before
+``params["layers"]`` (cache ``c1``/``c2``).  A model without them has
+the one stack and is traced as it always was.
+
+The serving entry points (``prefill``, ``prefill_chunk``,
+``decode_step``) run expert layers on the dropless path
+(``moe.moe_serve``); with ``stats=True`` the last two also return the
+expert counters, summed over the expert layers (``moe_serve``).
 """
 
 from __future__ import annotations
@@ -151,52 +162,75 @@ def attn_decode(p, cfg, x, k_cache, v_cache, pos, window):
 # ---------------------------------------------------------------------------
 # One decoder layer
 # ---------------------------------------------------------------------------
-def layer_init(key, cfg):
+def layer_init(key, cfg, dense: bool = False):
+    """One layer's params; ``dense`` gives a layer of the dense prefix
+    (a ``d_ff`` MLP where the others have experts)."""
     k_attn, k_ffn = jax.random.split(key)
     p: Dict[str, Any] = {
         "ln1": jnp.zeros((cfg.d_model,), cfg.param_dtype),
         "ln2": jnp.zeros((cfg.d_model,), cfg.param_dtype),
         "attn": (mla_mod.mla_init if cfg.mla else attn_init)(k_attn, cfg),
-        "ffn": (moe_mod.moe_init(k_ffn, cfg) if cfg.n_experts
+        "ffn": (moe_mod.moe_init(k_ffn, cfg) if cfg.n_experts and not dense
                 else C.mlp_init(k_ffn, cfg.d_model, cfg.d_ff,
                                 cfg.param_dtype)),
     }
     return p
 
 
-def layer_axes(cfg):
+def layer_axes(cfg, dense: bool = False):
     return {
         "ln1": (None,), "ln2": (None,),
         "attn": mla_mod.mla_axes(cfg) if cfg.mla else attn_axes(cfg),
-        "ffn": moe_mod.moe_axes(cfg) if cfg.n_experts else C.mlp_axes(),
+        "ffn": (moe_mod.moe_axes(cfg) if cfg.n_experts and not dense
+                else C.mlp_axes()),
     }
 
 
-def _ffn(p, cfg, x):
-    if cfg.n_experts:
-        return moe_mod.moe_apply(p, cfg, x)
+def _ffn(p, cfg, x, serve: bool = False):
+    """The layer's FFN: experts where its params have a router, else the
+    dense MLP.  Returns (out, aux loss), or with ``serve`` (out, expert
+    counters of ``moe.moe_serve``, None for a dense MLP)."""
+    if "router" in p:
+        return (moe_mod.moe_serve if serve else moe_mod.moe_apply)(p, cfg, x)
     return C.gated_mlp(x, p["wi_gate"], p["wi_up"], p["wo"],
-                       act=cfg.mlp_act,
-                       tp_psum=cfg.tp_psum), jnp.float32(0.0)
+                       act=cfg.mlp_act, tp_psum=cfg.tp_psum), (
+        None if serve else jnp.float32(0.0))
 
 
-def layer_apply(p, cfg, x, positions, window):
+def layer_apply(p, cfg, x, positions, window, serve: bool = False):
     flavor = mla_mod.mla_apply if cfg.mla else attn_apply
     h, slices = flavor(p["attn"], cfg, C.rms_norm(x, p["ln1"], cfg.norm_eps),
                        positions, window)
     x = x + h
-    h, aux = _ffn(p["ffn"], cfg, C.rms_norm(x, p["ln2"], cfg.norm_eps))
+    h, aux = _ffn(p["ffn"], cfg, C.rms_norm(x, p["ln2"], cfg.norm_eps),
+                  serve)
     return x + h, slices, aux
 
 
 def layer_decode(p, cfg, x, c1, c2, pos, window):
+    """One layer of a decode step; returns (x, c1, c2, expert counters
+    or None)."""
     flavor = mla_mod.mla_decode if cfg.mla else attn_decode
     h, (c1, c2) = flavor(p["attn"], cfg,
                          C.rms_norm(x, p["ln1"], cfg.norm_eps),
                          c1, c2, pos, window)
     x = x + h
-    h, _ = _ffn(p["ffn"], cfg, C.rms_norm(x, p["ln2"], cfg.norm_eps))
-    return x + h, c1, c2
+    h, counts = _ffn(p["ffn"], cfg, C.rms_norm(x, p["ln2"], cfg.norm_eps),
+                     serve=True)
+    return x + h, c1, c2, counts
+
+
+def _stacks(cfg, params):
+    """(layer params, windows, cache keys) of each layer stack in order:
+    the dense prefix where the model has one, then the other layers."""
+    w = layer_windows(cfg)
+    k = cfg.first_k_dense
+    out = []
+    if k:
+        out.append((params["dense_layers"], jnp.asarray(w[:k]),
+                    ("d1", "d2")))
+    out.append((params["layers"], jnp.asarray(w[k:]), ("c1", "c2")))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -205,13 +239,17 @@ def layer_decode(p, cfg, x, c1, c2, pos, window):
 def init_params(cfg, key) -> Dict[str, Any]:
     k_emb, k_layers, k_head, k_mtp, k_img = jax.random.split(key, 5)
     layer_keys = jax.random.split(k_layers, cfg.num_layers)
-    layers = jax.vmap(lambda k: layer_init(k, cfg))(layer_keys)
+    k = cfg.first_k_dense
+    layers = jax.vmap(lambda kk: layer_init(kk, cfg))(layer_keys[k:])
     p = {
         "embed": C.dense_init(k_emb, (cfg.vocab, cfg.d_model),
                               cfg.d_model, cfg.param_dtype),
         "layers": layers,
         "ln_f": jnp.zeros((cfg.d_model,), cfg.param_dtype),
     }
+    if k:
+        p["dense_layers"] = jax.vmap(
+            lambda kk: layer_init(kk, cfg, dense=True))(layer_keys[:k])
     if not cfg.tie_embeddings:
         p["head"] = C.dense_init(k_head, (cfg.d_model, cfg.vocab),
                                  cfg.d_model, cfg.param_dtype)
@@ -237,6 +275,8 @@ def param_axes(cfg) -> Dict[str, Any]:
         "layers": stack(layer_axes(cfg)),
         "ln_f": (None,),
     }
+    if cfg.first_k_dense:
+        p["dense_layers"] = stack(layer_axes(cfg, dense=True))
     if not cfg.tie_embeddings:
         p["head"] = ("fsdp", "vocab")
     if cfg.mtp:
@@ -275,7 +315,6 @@ def forward(cfg, params, tokens, patches=None):
     b, s = tokens.shape
     x = _embed_in(cfg, params, tokens, patches)
     positions = jnp.arange(s)[None, :]
-    windows = jnp.asarray(layer_windows(cfg))
 
     def body(carry, xs):
         x, aux = carry
@@ -283,9 +322,10 @@ def forward(cfg, params, tokens, patches=None):
         x, _, a = layer_apply(lp, cfg, x, positions, w)
         return (x, aux + a), None
 
-    (x, aux), _ = jax.lax.scan(C.maybe_remat(cfg, body),
-                               (x, jnp.float32(0.0)),
-                               (params["layers"], windows))
+    aux = jnp.float32(0.0)
+    for layers, windows, _ in _stacks(cfg, params):
+        (x, aux), _ = jax.lax.scan(C.maybe_remat(cfg, body), (x, aux),
+                                   (layers, windows))
     logits = _head(cfg, params, x)
     extras = {"aux_loss": aux * cfg.moe_aux_alpha}
     if cfg.mtp:
@@ -312,26 +352,34 @@ def mtp_logits(cfg, params, hidden, tokens):
 # ---------------------------------------------------------------------------
 def init_cache(cfg, batch: int, max_len: int):
     s = cache_len(cfg, max_len)
-    L = cfg.num_layers
+    k = cfg.first_k_dense
+    L = cfg.num_layers - k
     if cfg.mla:
         shapes = ((batch, s, 1, cfg.kv_lora_rank),
                   (batch, s, 1, cfg.qk_rope_dim))
     else:
         shapes = ((batch, s, cfg.n_kv_heads, cfg.head_dim),) * 2
-    return {
+    cache = {
         "c1": jnp.zeros((L,) + shapes[0], cfg.dtype),
         "c2": jnp.zeros((L,) + shapes[1], cfg.dtype),
         "pos": jnp.zeros((batch,), jnp.int32),
     }
+    if k:
+        cache["d1"] = jnp.zeros((k,) + shapes[0], cfg.dtype)
+        cache["d2"] = jnp.zeros((k,) + shapes[1], cfg.dtype)
+    return cache
 
 
 def cache_axes(cfg):
     kv = (None, None) if cfg.mla else ("kv_heads", "head_dim")
-    return {
+    axes = {
         "c1": ("layers", "batch", "kv_seq") + kv,
         "c2": ("layers", "batch", "kv_seq") + kv,
         "pos": ("batch",),
     }
+    if cfg.first_k_dense:
+        axes["d1"], axes["d2"] = axes["c1"], axes["c2"]
+    return axes
 
 
 def prefill(cfg, params, tokens, cache, patches=None):
@@ -339,7 +387,6 @@ def prefill(cfg, params, tokens, cache, patches=None):
     b, s = tokens.shape
     x = _embed_in(cfg, params, tokens, patches)
     positions = jnp.arange(s)[None, :]
-    windows = jnp.asarray(layer_windows(cfg))
     slen = cache["c1"].shape[2]
 
     def fit(t):
@@ -353,14 +400,19 @@ def prefill(cfg, params, tokens, cache, patches=None):
             return jnp.pad(t, pad)
         return t
 
-    def body(x, xs):
-        lp, w = xs
-        x, (c1, c2), _ = layer_apply(lp, cfg, x, positions, w)
-        return x, (fit(c1.astype(cfg.dtype)), fit(c2.astype(cfg.dtype)))
+    cache = {}
+    for layers, windows, (k1, k2) in _stacks(cfg, params):
+        sliced, index, complete = _serving_layers(layers)
 
-    x, (c1s, c2s) = jax.lax.scan(body, x, (params["layers"], windows))
-    cache = {"c1": c1s, "c2": c2s,
-             "pos": jnp.full((b,), s, jnp.int32)}
+        def body(x, xs):
+            lp, w, *extra = xs
+            x, (c1, c2), _ = layer_apply(complete(lp, extra), cfg, x,
+                                         positions, w, serve=True)
+            return x, (fit(c1.astype(cfg.dtype)), fit(c2.astype(cfg.dtype)))
+
+        x, (cache[k1], cache[k2]) = jax.lax.scan(body, x,
+                                                 (sliced, windows) + index)
+    cache["pos"] = jnp.full((b,), s, jnp.int32)
     return _head(cfg, params, x[:, -1:]), cache
 
 
@@ -391,16 +443,69 @@ def attn_chunk(p, cfg, x, k_cache, v_cache, positions, window, start):
 
 def layer_chunk(p, cfg, x, c1, c2, positions, window, start):
     """One decoder layer over a prefill chunk (the chunk twin of
-    ``layer_apply``/``layer_decode``)."""
-    h, (c1, c2) = attn_chunk(p["attn"], cfg,
-                             C.rms_norm(x, p["ln1"], cfg.norm_eps),
-                             c1, c2, positions, window, start)
+    ``layer_apply``/``layer_decode``); returns (x, c1, c2, expert
+    counters or None)."""
+    flavor = mla_mod.mla_chunk if cfg.mla else attn_chunk
+    h, (c1, c2) = flavor(p["attn"], cfg,
+                         C.rms_norm(x, p["ln1"], cfg.norm_eps),
+                         c1, c2, positions, window, start)
     x = x + h
-    h, _ = _ffn(p["ffn"], cfg, C.rms_norm(x, p["ln2"], cfg.norm_eps))
-    return x + h, c1, c2
+    h, counts = _ffn(p["ffn"], cfg, C.rms_norm(x, p["ln2"], cfg.norm_eps),
+                     serve=True)
+    return x + h, c1, c2, counts
 
 
-def prefill_chunk(cfg, params, tokens, cache, start, length):
+def _serving_layers(layers):
+    """What a serving scan slices per layer, and ``complete(lp, xs)``
+    that finishes a layer's params in the body.  An expert stack keeps
+    its routed experts' weights whole, beside the layer's index (its
+    last scanned input), so the grouped matmul reads them in place and
+    the scan copies no layer's experts out (``moe.moe_serve``)."""
+    if "router" not in layers["ffn"]:
+        return layers, (), lambda lp, extra: lp
+    ffn = layers["ffn"]
+    whole = {k: ffn[k] for k in moe_mod.EXPERT_WEIGHTS}
+    rest = dict(layers, ffn={k: v for k, v in ffn.items()
+                             if k not in whole})
+    index = (jnp.arange(ffn["router"].shape[0]),)
+    return rest, index, lambda lp, extra: dict(
+        lp, ffn=dict(lp["ffn"], **whole, layer=extra[0]))
+
+
+def _scan_cache(cfg, params, cache, x, layer_fn):
+    """Run ``x`` through every layer stack, each layer updating its rows
+    of the cache: ``layer_fn(lp, x, c1, c2, window)`` returns (x, c1,
+    c2, counters or None).  Returns (x, the cache's layer leaves,
+    counters summed over the expert layers)."""
+    leaves, counts = {}, None
+    for layers, windows, (k1, k2) in _stacks(cfg, params):
+        experts = "router" in layers["ffn"]
+        sliced, index, complete = _serving_layers(layers)
+
+        def body(x, xs):
+            lp, w, c1, c2, *extra = xs
+            x, c1, c2, n = layer_fn(complete(lp, extra), x, c1, c2, w)
+            return x, ((c1, c2, n) if experts else (c1, c2))
+
+        x, ys = jax.lax.scan(body, x, (sliced, windows, cache[k1],
+                                       cache[k2]) + index)
+        leaves[k1], leaves[k2] = ys[0], ys[1]
+        if experts:
+            n = jnp.sum(ys[2], axis=0)
+            counts = n if counts is None else counts + n
+    return x, leaves, counts
+
+
+def _with_counts(out, counts, stats: bool):
+    """``out`` with the expert counters appended when ``stats`` (zeros
+    for a model without experts)."""
+    if not stats:
+        return out
+    return out + (jnp.zeros((2,), jnp.int32) if counts is None else counts,)
+
+
+def prefill_chunk(cfg, params, tokens, cache, start, length,
+                  stats: bool = False):
     """Incremental prefill: process ``length`` (≤ C) prompt tokens at
     absolute positions ``[start, start+length)`` against an existing
     cache.
@@ -413,47 +518,36 @@ def prefill_chunk(cfg, params, tokens, cache, start, length):
     ``prefill`` (tested), because the chunk queries see exactly the
     same keys in the same order: the cache prefix holds the earlier
     chunks' K/V at their absolute rows and ``q_offset`` keeps the
-    causal/window masks absolute.
+    causal/window masks absolute.  An MLA cache holds latents: each
+    chunk writes its latents and expands the whole cache per head.
+    With ``stats`` the expert counters come third (every position of
+    the chunk is routed, its pad too).
 
     Requires a full-context cache (``cache_len == max_len``): with a
     ring cache the chunk's absolute row indices would alias, so callers
     gate chunked prefill off for all-sliding-window models (the
-    scheduler does).  MLA caches store latents, not K/V, and are not
-    supported — ``repro.serve`` falls back to whole-prompt prefill.
+    scheduler does).
     """
-    if cfg.mla:
-        raise NotImplementedError(
-            "chunked prefill is not supported for MLA caches")
     b, c = tokens.shape
     x = _embed_in(cfg, params, tokens)
     positions = start + jnp.arange(c)[None, :]
-    windows = jnp.asarray(layer_windows(cfg))
-
-    def body(x, xs):
-        lp, w, c1, c2 = xs
-        x, c1, c2 = layer_chunk(lp, cfg, x, c1, c2, positions, w, start)
-        return x, (c1, c2)
-
-    x, (c1s, c2s) = jax.lax.scan(
-        body, x, (params["layers"], windows, cache["c1"], cache["c2"]))
-    new_cache = {"c1": c1s, "c2": c2s,
-                 "pos": jnp.full_like(cache["pos"], start + length)}
+    x, leaves, counts = _scan_cache(
+        cfg, params, cache, x,
+        lambda lp, x, c1, c2, w: layer_chunk(lp, cfg, x, c1, c2, positions,
+                                             w, start))
+    new_cache = dict(leaves, pos=jnp.full_like(cache["pos"], start + length))
     last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
-    return _head(cfg, params, last), new_cache
+    return _with_counts((_head(cfg, params, last), new_cache), counts, stats)
 
 
-def decode_step(cfg, params, cache, tokens):
-    """One decode step: tokens (B,1) -> (logits (B,1,V), updated cache)."""
+def decode_step(cfg, params, cache, tokens, stats: bool = False):
+    """One decode step: tokens (B,1) -> (logits (B,1,V), updated cache),
+    with ``stats`` the expert counters third (every row of the batch is
+    routed, empty slots too)."""
     x = _embed_in(cfg, params, tokens)
     pos = cache["pos"]
-    windows = jnp.asarray(layer_windows(cfg))
-
-    def body(x, xs):
-        lp, w, c1, c2 = xs
-        x, c1, c2 = layer_decode(lp, cfg, x, c1, c2, pos, w)
-        return x, (c1, c2)
-
-    x, (c1s, c2s) = jax.lax.scan(
-        body, x, (params["layers"], windows, cache["c1"], cache["c2"]))
-    new_cache = {"c1": c1s, "c2": c2s, "pos": pos + 1}
-    return _head(cfg, params, x), new_cache
+    x, leaves, counts = _scan_cache(
+        cfg, params, cache, x,
+        lambda lp, x, c1, c2, w: layer_decode(lp, cfg, x, c1, c2, pos, w))
+    new_cache = dict(leaves, pos=pos + 1)
+    return _with_counts((_head(cfg, params, x), new_cache), counts, stats)

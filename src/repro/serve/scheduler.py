@@ -190,7 +190,8 @@ class Scheduler:
                  clock: Callable[[], float] = time.perf_counter,
                  engine_worker: str = "thread",
                  device_source: Optional[Callable] = None,
-                 precision_info: Optional[dict] = None) -> None:
+                 precision_info: Optional[dict] = None,
+                 donate_params: bool = False) -> None:
         self.model = model
         self.cfg = model.cfg
         self.options = options
@@ -200,8 +201,11 @@ class Scheduler:
         self.precision_info = dict(precision_info) if precision_info \
             else None
         if options.fold:
+            # ``donate_params``: the caller hands its weights over, and
+            # each folded matrix takes its original's buffer.
             from ..inference.fold_norms import fold_norms
-            params, self.fold_report = fold_norms(self.cfg, params)
+            params, self.fold_report = fold_norms(self.cfg, params,
+                                                  donate=donate_params)
         else:
             self.fold_report = {"folds": 0}
         self.params = params
@@ -239,10 +243,21 @@ class Scheduler:
         self.metrics = SchedulerMetrics()
         self.last_token = np.zeros((options.slots, 1), np.int32)
 
+        # Expert counters (models with routed experts): the decode and
+        # chunk programs return them as a small third output, read only
+        # after the step's logits were (see _flush_counts).
+        self._stats = {"stats": True} if getattr(
+            model, "counts_experts", False) else {}
+        self._pending_counts: List[tuple] = []
+        self._expert_counts = {
+            kind: {"calls": 0, "held_choices": 0, "experts_hit": 0}
+            for kind in ("decode", "chunk")}
+
         # compiled programs (donated cache: in-place buffer reuse)
         def decode_body(p, c, t):
-            logits, c = model.decode_step(p, self._compute_view(c), t)
-            return logits, self._constrain_cache(c)
+            logits, c, *counts = model.decode_step(
+                p, self._compute_view(c), t, **self._stats)
+            return (logits, self._constrain_cache(c), *counts)
 
         self._decode = jax.jit(decode_body, donate_argnums=(1,))
         self._prefill = jax.jit(lambda p, b, c: model.prefill(p, b, c))
@@ -423,16 +438,18 @@ class Scheduler:
             def step(p, c, t):
                 c = self._compute_view(c)
                 if b >= opts.slots:
-                    logits, c = self.model.decode_step(p, c, t)
-                    return logits, self._constrain_cache(c)
+                    logits, c, *counts = self.model.decode_step(
+                        p, c, t, **self._stats)
+                    return (logits, self._constrain_cache(c), *counts)
                 sub = jax.tree.map(
                     lambda l: l[:b] if l.ndim == 1 else l[:, :b], c)
-                logits, sub = self.model.decode_step(p, sub, t[:b])
+                logits, sub, *counts = self.model.decode_step(
+                    p, sub, t[:b], **self._stats)
                 axis = lambda l: 0 if l.ndim == 1 else 1
                 new_c = jax.tree.map(
                     lambda f, s: jax.lax.dynamic_update_slice_in_dim(
                         f, s, 0, axis=axis(f)), c, sub)
-                return logits, self._constrain_cache(new_c)
+                return (logits, self._constrain_cache(new_c), *counts)
 
             fn = jax.jit(step, donate_argnums=(1,))
             return fn.lower(params_spec, full_spec, tok_spec).compile()
@@ -479,9 +496,9 @@ class Scheduler:
         optional shared-prefix snapshot cache.
 
         Families without incremental prefill keep whole-prompt prefill
-        silently (MLA latent caches, vlm/audio extra inputs, ring
-        caches whose capacity is the window, not ``max_len``) —
-        surfaced in ``summary()["chunked_prefill"]["enabled"]``.
+        silently (vlm/audio extra inputs, ring caches whose capacity is
+        the window, not ``max_len``) — surfaced in
+        ``summary()["chunked_prefill"]["enabled"]``.
         """
         from ..runtime.buckets import BucketPolicy
         from ..runtime.engine_cache import EngineCache
@@ -503,7 +520,7 @@ class Scheduler:
             # place (PrefixCache copies before/after, never aliases it)
             fn = jax.jit(
                 lambda p, t, c, s, n: self.model.prefill_chunk(
-                    p, t, c, s, n),
+                    p, t, c, s, n, **self._stats),
                 donate_argnums=(2,))
             return fn.lower(params_spec, t_spec, self._aot_specs(cache_spec),
                             s_spec, s_spec).compile()
@@ -770,9 +787,10 @@ class Scheduler:
             chunk = np.zeros((1, chunk_len), np.int32)
             chunk[0, :n] = task.prompt[task.offset:task.offset + n]
             entry, _, _ = self._chunk_engine.get(1, n)
-            task.logits, task.cache = entry(
+            task.logits, task.cache, *counts = entry(
                 self.params, jnp.asarray(chunk), task.cache,
                 jnp.int32(task.offset), jnp.int32(n))
+            self._note_counts("chunk", counts)
             task.offset += n
             self.metrics.prefill_chunks += 1
             if (task.snapshot_key is not None
@@ -840,9 +858,36 @@ class Scheduler:
         for src, dst in self.slot_manager.compact():
             self.last_token[dst, 0] = self.last_token[src, 0]
         entry, _, _ = self._decode_engine.get(k)
-        logits, self.slot_manager.cache = entry(
+        logits, self.slot_manager.cache, *counts = entry(
             self.params, self.slot_manager.cache, self._tokens())
+        self._note_counts("decode", counts)
         return logits[:, 0]
+
+    # -- expert counters -----------------------------------------------
+    def _note_counts(self, kind: str, counts: List[Any]) -> None:
+        """Keep a program's expert counters (none for a model without
+        experts), their copy to the host started."""
+        for c in counts:
+            c.copy_to_host_async()
+            self._pending_counts.append((kind, c))
+
+    def _flush_counts(self) -> None:
+        """Add the kept counters to the totals and publish each call's
+        as ``jax.monitoring`` scalars
+        (``/repro/serve/experts/<decode|chunk>/<held_choices|experts_hit>``).
+        Called once the step's logits were read, when the programs that
+        made the counters have finished and their copies landed."""
+        for kind, c in self._pending_counts:
+            held, hit = (int(v) for v in np.asarray(c))
+            tot = self._expert_counts[kind]
+            tot["calls"] += 1
+            tot["held_choices"] += held
+            tot["experts_hit"] += hit
+            jax.monitoring.record_scalar(
+                f"/repro/serve/experts/{kind}/held_choices", held)
+            jax.monitoring.record_scalar(
+                f"/repro/serve/experts/{kind}/experts_hit", hit)
+        self._pending_counts = []
 
     # -- the step loop -------------------------------------------------
     def step(self) -> int:
@@ -867,8 +912,9 @@ class Scheduler:
                 active = self.slot_manager.active_slots()  # post-compaction
             else:
                 decode = self._decode_aot or self._decode
-                logits, self.slot_manager.cache = decode(
+                logits, self.slot_manager.cache, *counts = decode(
                     self.params, self.slot_manager.cache, self._tokens())
+                self._note_counts("decode", counts)
                 logits = logits[:, 0]
         self.metrics.decode_steps += 1
         self.metrics.decode_slot_steps += len(active)
@@ -887,6 +933,7 @@ class Scheduler:
                     self._retire(slot, "eos")
                 elif st.remaining <= 0:
                     self._retire(slot, "length")
+        self._flush_counts()
         self.metrics.last_step_at = self.clock()
         return self.slot_manager.num_active()
 
@@ -952,6 +999,13 @@ class Scheduler:
             }
         if self._prefix_cache is not None:
             out["prefix_cache"] = self._prefix_cache.stats()
+        if self._stats:
+            # routed token-choices that fell on the experts held here,
+            # and held experts hit, summed over the expert layers and
+            # over the decode steps / prefill chunks run so far
+            self._flush_counts()
+            out["experts"] = {k: dict(v)
+                              for k, v in self._expert_counts.items()}
         if self.options.mesh is not None:
             out["faults"] = [dict(f) for f in self._faults]
             out["sharding"] = self._sharding_summary()

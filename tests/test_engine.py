@@ -45,6 +45,53 @@ def test_fold_norms_moe():
                                rtol=5e-2, atol=5e-2)
 
 
+@pytest.mark.parametrize("arch", ["qwen2.5-14b", "deepseek-v3-671b"])
+def test_fold_norms_donates_in_place(arch):
+    """With ``donate`` each folded matrix takes its original's buffer:
+    the originals are deleted, nothing else is, and the folded model
+    computes what the unfolded one does."""
+    cfg = get_config(arch, smoke=True)
+    m = get_model(cfg)
+    batch = {"tokens": jnp.arange(16, dtype=jnp.int32)[None, :] % cfg.vocab}
+    params = m.init(jax.random.PRNGKey(0))
+    l0, _ = m.forward(params, batch)
+    keep = jax.tree.map(jnp.copy, params)
+    folded, rep = fold_norms(cfg, params, donate=True)
+    assert rep["donated"]
+    gone = [p for p in jax.tree.leaves(params) if p.is_deleted()]
+    assert len(gone) == rep["folds"]
+    assert params["head"].is_deleted() and not folded["head"].is_deleted()
+    assert not params["embed"].is_deleted()
+    l1, _ = m.forward(folded, batch)
+    np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),
+                               rtol=5e-2, atol=5e-2)
+    again, _ = fold_norms(cfg, keep)             # without donation
+    assert not any(p.is_deleted() for p in jax.tree.leaves(keep))
+    for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(folded)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_serve_hands_the_weights_over(setup):
+    """``repro.serve`` folds the executable's weights in place: the
+    originals are gone and the executable keeps the folded ones."""
+    import repro
+    cfg, m, params = setup
+    exe = repro.compile(cfg, repro.CompileOptions(target="engine"),
+                        params=jax.tree.map(jnp.copy, params))
+    head = exe.params["head"]
+    sched = repro.serve(exe, repro.SchedulerOptions(slots=1, max_len=32))
+    try:
+        assert head.is_deleted()
+        assert exe.params is sched.params
+        assert sched.fold_report["donated"]
+        batch = {"tokens": jnp.arange(8, dtype=jnp.int32)[None, :]}
+        np.testing.assert_allclose(
+            np.asarray(exe(**batch)["logits"]),
+            np.asarray(m.forward(params, batch)[0]), rtol=5e-2, atol=5e-2)
+    finally:
+        sched.shutdown()
+
+
 def test_engine_drains_queue(setup):
     cfg, m, params = setup
     eng = Engine(m, params, slots=2, max_len=48)

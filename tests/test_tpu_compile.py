@@ -129,3 +129,33 @@ def test_decode_attention_compiles(one_chip, fast):
         ((b, s, hkv, d), jnp.float32), ((b,), jnp.int32))
     assert "tpu_custom_call" in text
     assert "%decode_attention" in text
+
+
+@pytest.mark.parametrize("tokens", [32, 256], ids=["decode", "chunk"])
+def test_moe_gmm_compiles(one_chip, tokens):
+    """DeepSeek-V3's expert MLP over its 8 held experts (d 7168, expert
+    width 2048, bf16; the stack of 4 expert layers, one chosen by index)
+    in the tiled layout of a 32-slot decode step and a 256-token prefill
+    chunk: the gated kernel and the down projection, with the weight
+    blocks ``ops.k_block`` gives them, within the kernel's scoped
+    VMEM."""
+    from repro.kernels.grouped_matmul import ops
+    from repro.kernels.grouped_matmul.kernel import gmm_p
+    layers, g, d, f = 4, 8, 7168, 2048
+    tm = ops.row_tile(tokens * 8)
+    rows = (-(-tokens * 8 // tm) + g) * tm
+    tiles = rows // tm
+
+    def expert_mlp(x, wg, wu, wo, tg, na, ly):
+        h = gmm_p(x, wg, tg, na, ly, wu, tm=tm,
+                  bk=ops.k_block(d, f, 2, 2), interpret=False)
+        return gmm_p(h, wo, tg, na, ly, tm=tm, bk=ops.k_block(f, d, 2, 1),
+                     interpret=False)
+
+    stack = (layers, g, d, f)
+    text = _compile(expert_mlp, one_chip, ((rows, d), jnp.bfloat16),
+                    (stack, jnp.bfloat16), (stack, jnp.bfloat16),
+                    ((layers, g, f, d), jnp.bfloat16), ((tiles,), jnp.int32),
+                    ((1,), jnp.int32), ((1,), jnp.int32))
+    assert text.count("tpu_custom_call") == 2
+    assert "%moe_gmm" in text
