@@ -59,7 +59,8 @@ def serve(executable, options: Optional[SchedulerOptions] = None, *,
     CompileOptions(target="engine"))``.  Remaining keyword args override
     ``SchedulerOptions`` fields (``repro.serve(exe, slots=8)``);
     ``sampler`` and ``clock`` are injection points for tests
-    (deterministic token streams, fake time).
+    (scripted token streams sampled on the host, one read per slot and
+    step; fake time).
 
     When the executable was compiled with ``CompileOptions(mesh=...)``
     and the scheduler options leave ``mesh`` unset, the compile-time

@@ -26,7 +26,7 @@ from .metrics import RequestMetrics, SchedulerMetrics
 from .options import ADMISSION_POLICIES, SchedulerOptions
 from .prefix import PrefixCache
 from .scheduler import (Completion, QueueFullError, Request, Scheduler,
-                        TemperatureSampler)
+                        sample_rows)
 from .slots import SlotManager, SlotState
 
 __all__ = [
@@ -41,7 +41,7 @@ __all__ = [
     "SchedulerOptions",
     "SlotManager",
     "SlotState",
-    "TemperatureSampler",
+    "sample_rows",
 ]
 
 

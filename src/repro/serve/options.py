@@ -39,7 +39,8 @@ class SchedulerOptions:
                   waiting.  ``None`` = unbounded.
     fold:         run ``fold_norms`` on the params at scheduler build
                   (compile-time weight rewriting, paper §3.5).
-    seed:         PRNG seed for the default temperature sampler.
+    seed:         PRNG seed of the sampling the programs do (requests
+                  with a temperature above 0).
     buckets:      a :class:`repro.runtime.BucketPolicy` enabling
                   shape-polymorphic serving: each step decodes at the
                   smallest warm batch bucket covering the active slots

@@ -13,8 +13,10 @@ The step loop rebatches every decode step:
        program on the donated buffer, so steady-state decode performs no
        new device allocations (the framework-scale version of the
        paper's in-place memory planning);
-    3. sample + evict — per-slot sampling, EOS / length retirement frees
-       slots for the next iteration's admissions.
+    3. sample + evict — the decode program samples every row's token
+       itself (:func:`sample_rows`), so the host reads one ``(slots,)``
+       vector a step; EOS / length retirement frees slots for the next
+       iteration's admissions.
 
 Requests whose prompts share a common head (the "system prompt"
 scenario) prefill that head once: with ``prefix_cache`` enabled the
@@ -161,29 +163,39 @@ class QueueFullError(RuntimeError):
     """Raised by ``submit`` when ``SchedulerOptions.max_queue`` is hit."""
 
 
-class TemperatureSampler:
-    """Default sampler: greedy at temperature 0, categorical otherwise.
+def sample_rows(logits: jnp.ndarray, temperature: jnp.ndarray,
+                key: jax.Array) -> jnp.ndarray:
+    """One token per row of ``logits`` (B, vocab), as (B,) int32: the
+    argmax where the row's ``temperature`` (B,) is 0 or less, else a
+    categorical draw from ``logits / temperature`` under the row's own
+    key, split from ``key``.  Traced into the decode program; the draw
+    runs only when some row has a temperature above 0, so an all-greedy
+    step pays the argmax alone."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    hot = temperature > 0
 
-    The sampler protocol is ``sample(logits, temperature, *, uid, index)
-    -> int`` with ``logits`` of shape (1, vocab) and ``index`` the
-    number of tokens already generated for that request — tests inject
-    fakes that script tokens per request.
-    """
+    def draw():
+        keys = jax.random.split(key, logits.shape[0])
+        scale = jnp.where(hot, temperature, 1.0).astype(jnp.float32)
+        drawn = jax.vmap(jax.random.categorical)(
+            keys, logits.astype(jnp.float32) / scale[:, None])
+        return jnp.where(hot, drawn.astype(jnp.int32), greedy)
 
-    def __init__(self, seed: int = 0) -> None:
-        self.key = jax.random.PRNGKey(seed)
-
-    def __call__(self, logits: jnp.ndarray, temperature: float, *,
-                 uid: int, index: int) -> int:
-        if temperature <= 0.0:
-            return int(jnp.argmax(logits, axis=-1)[0])
-        self.key, sub = jax.random.split(self.key)
-        return int(jax.random.categorical(
-            sub, logits / temperature, axis=-1)[0])
+    return jax.lax.cond(jnp.any(hot), draw, lambda: greedy)
 
 
 class Scheduler:
-    """Drive a compiled model under concurrent multi-request load."""
+    """Drive a compiled model under concurrent multi-request load.
+
+    Tokens are sampled inside the decode program (:func:`sample_rows`,
+    keyed by ``options.seed`` and a draw count).  ``sampler`` is a seam
+    for tests: a host callable ``sampler(logits, temperature, *, uid,
+    index) -> int``, with ``logits`` of shape (1, vocab) and ``index``
+    the number of tokens the request already has, that scripts tokens
+    per request.  Given one, the decode program returns its logits and
+    the host calls the sampler once per active slot, each call a
+    blocking read: slow by construction, and kept for tests only.
+    """
 
     def __init__(self, model, params, options: SchedulerOptions, *,
                  sampler: Optional[Callable] = None,
@@ -209,7 +221,7 @@ class Scheduler:
         else:
             self.fold_report = {"folds": 0}
         self.params = params
-        self.sampler = sampler or TemperatureSampler(options.seed)
+        self.sampler = sampler      # None: the programs sample
         self.clock = clock
 
         # data×model-parallel serving (repro.dist): bind the mesh before
@@ -242,6 +254,12 @@ class Scheduler:
         self.request_metrics: Dict[int, RequestMetrics] = {}
         self.metrics = SchedulerMetrics()
         self.last_token = np.zeros((options.slots, 1), np.int32)
+        self.temperatures = np.zeros((options.slots,), np.float32)
+        # sampling programs run so far: each one's key is the seed's
+        # folded with its count, so no key makes a round trip
+        self._draws = 0
+        self._sampling = {"program_steps": 0, "host_steps": 0,
+                          "token_reads": 0}
 
         # Expert counters (models with routed experts): the decode and
         # chunk programs return them as a small third output, read only
@@ -253,14 +271,20 @@ class Scheduler:
             kind: {"calls": 0, "held_choices": 0, "experts_hit": 0}
             for kind in ("decode", "chunk")}
 
-        # compiled programs (donated cache: in-place buffer reuse)
-        def decode_body(p, c, t):
+        # compiled programs (donated cache: in-place buffer reuse).  A
+        # decode program returns the cache first: donation gives each
+        # donated leaf the first output of its shape, and a (slots,)
+        # int32 token vector ahead of the cache would take the position
+        # vector's buffer.
+        def decode_body(p, c, t, temps, n):
             logits, c, *counts = model.decode_step(
                 p, self._compute_view(c), t, **self._stats)
-            return (logits, self._constrain_cache(c), *counts)
+            return (self._constrain_cache(c),
+                    self._head(logits[:, 0], temps, n), *counts)
 
         self._decode = jax.jit(decode_body, donate_argnums=(1,))
         self._prefill = jax.jit(lambda p, b, c: model.prefill(p, b, c))
+        self._sample_first = jax.jit(self._sample)
 
         # shape-polymorphic serving (repro.runtime): warm programs per
         # bucket, background compiles.  None = fixed-shape (PR-5) path.
@@ -285,8 +309,27 @@ class Scheduler:
                 self._aot_specs(self.params),
                 self._aot_specs(self.slot_manager.cache,
                                 shard=self._leaf_sharding),
-                self._aot_specs(jax.ShapeDtypeStruct(
-                    (options.slots, 1), jnp.int32))).compile()
+                *self._step_specs()).compile()
+
+    # -- sampling --------------------------------------------------------
+    def _sample(self, logits, temps, n):
+        """Traced: :func:`sample_rows` under the key of draw ``n``."""
+        key = jax.random.fold_in(jax.random.PRNGKey(self.options.seed), n)
+        return sample_rows(logits, temps, key)
+
+    def _head(self, logits, temps, n):
+        """What a decode program returns for its rows' logits (B,
+        vocab): their tokens, or the logits when a host sampler was
+        handed in."""
+        if self.sampler is not None:
+            return logits
+        return self._sample(logits, temps, n)
+
+    def _next_draw(self) -> np.uint32:
+        """The index of the next sampling program's draw."""
+        n = self._draws
+        self._draws += 1
+        return np.uint32(n & 0xFFFFFFFF)
 
     # -- mesh placement ------------------------------------------------
     def _rule_axes(self, logical: str):
@@ -368,15 +411,26 @@ class Scheduler:
 
         return jax.tree.map(one, tree)
 
-    def _tokens(self) -> jnp.ndarray:
-        """The last-token batch, placed for the decode program (the
-        replicated spec its AOT lowering committed to)."""
-        t = jnp.asarray(self.last_token)
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            t = jax.device_put(
-                t, NamedSharding(self.mesh, PartitionSpec()))
-        return t
+    def _step_specs(self) -> tuple:
+        """Specs of the decode program's per-step inputs (see
+        ``_step_inputs``)."""
+        n = self.options.slots
+        return self._aot_specs((
+            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((), jnp.uint32)))
+
+    def _step_inputs(self) -> tuple:
+        """The decode program's per-step inputs: the last-token batch,
+        the slots' temperatures and the draw index, in one transfer,
+        placed as its AOT lowering committed to (replicated under a
+        mesh)."""
+        args = (self.last_token, self.temperatures, self._next_draw())
+        if self.mesh is None:
+            return jax.device_put(args)
+        from jax.sharding import NamedSharding, PartitionSpec
+        return jax.device_put(args, NamedSharding(self.mesh,
+                                                  PartitionSpec()))
 
     def _check_mesh(self) -> None:
         """Step-loop fault check: raise (and record) a typed
@@ -423,8 +477,7 @@ class Scheduler:
             jax.eval_shape(
                 lambda: self.model.init_cache(opts.slots, opts.max_len)),
             shard=self._leaf_sharding if self.mesh is not None else None)
-        tok_spec = self._aot_specs(
-            jax.ShapeDtypeStruct((opts.slots, 1), jnp.int32))
+        step_specs = self._step_specs()
 
         def build_decode(bucket):
             # EVERY bucket's program takes (and donates) the FULL
@@ -435,12 +488,13 @@ class Scheduler:
             # step-buffer discipline of the paper's memory planner).
             b = bucket.batch
 
-            def step(p, c, t):
+            def step(p, c, t, temps, n):
                 c = self._compute_view(c)
                 if b >= opts.slots:
                     logits, c, *counts = self.model.decode_step(
                         p, c, t, **self._stats)
-                    return (logits, self._constrain_cache(c), *counts)
+                    return (self._constrain_cache(c),
+                            self._head(logits[:, 0], temps, n), *counts)
                 sub = jax.tree.map(
                     lambda l: l[:b] if l.ndim == 1 else l[:, :b], c)
                 logits, sub, *counts = self.model.decode_step(
@@ -449,10 +503,11 @@ class Scheduler:
                 new_c = jax.tree.map(
                     lambda f, s: jax.lax.dynamic_update_slice_in_dim(
                         f, s, 0, axis=axis(f)), c, sub)
-                return (logits, self._constrain_cache(new_c), *counts)
+                return (self._constrain_cache(new_c),
+                        self._head(logits[:, 0], temps[:b], n), *counts)
 
             fn = jax.jit(step, donate_argnums=(1,))
-            return fn.lower(params_spec, full_spec, tok_spec).compile()
+            return fn.lower(params_spec, full_spec, *step_specs).compile()
 
         self._decode_engine = EngineCache(
             BucketPolicy(batch_buckets=policy.batch_buckets),
@@ -809,9 +864,16 @@ class Scheduler:
     def _activate(self, slot: int, req: Request, logits: jnp.ndarray,
                   one_cache: Any) -> None:
         """Prefill is done: sample the first token from its (1, vocab)
-        logits, splice the single-row cache into ``slot`` and record
+        logits (one program and one read; the host sampler on its
+        path), splice the single-row cache into ``slot`` and record
         first-token metrics (including the SLO verdict)."""
-        tok = self.sampler(logits, req.temperature, uid=req.uid, index=0)
+        if self.sampler is None:
+            tok = int(self._sample_first(
+                logits, np.full((1,), req.temperature, np.float32),
+                self._next_draw())[0])
+        else:
+            tok = self.sampler(logits, req.temperature, uid=req.uid,
+                               index=0)
         # clamp so prompt + generated tokens can never outrun the
         # per-slot cache capacity
         plen = int(np.asarray(req.prompt).shape[-1])
@@ -822,6 +884,7 @@ class Scheduler:
             eos_id=req.eos_id,
             temperature=req.temperature), one_cache)
         self.last_token[slot, 0] = tok
+        self.temperatures[slot] = req.temperature
         self.generated[req.uid] = [tok]
         m = self.request_metrics[req.uid]
         m.first_token_at = self.clock()
@@ -837,6 +900,7 @@ class Scheduler:
     # -- retirement ----------------------------------------------------
     def _retire(self, slot: int, reason: str) -> None:
         st = self.slot_manager.evict(slot)
+        self.temperatures[slot] = 0.0    # a free row draws nothing
         m = self.request_metrics[st.uid]
         m.finished_at = self.clock()
         m.finish_reason = reason
@@ -854,14 +918,17 @@ class Scheduler:
         slice and KV write-back happen inside the compiled program, so
         the cache buffer is reused in place every step (bit-identical
         per row to decoding at the full slot count, minus the work for
-        the empty rows; returned logits cover the bucket's rows)."""
+        the empty rows; the returned tokens, or logits, cover the
+        bucket's rows)."""
         for src, dst in self.slot_manager.compact():
             self.last_token[dst, 0] = self.last_token[src, 0]
+            self.temperatures[dst] = self.temperatures[src]
+            self.temperatures[src] = 0.0
         entry, _, _ = self._decode_engine.get(k)
-        logits, self.slot_manager.cache, *counts = entry(
-            self.params, self.slot_manager.cache, self._tokens())
+        self.slot_manager.cache, out, *counts = entry(
+            self.params, self.slot_manager.cache, *self._step_inputs())
         self._note_counts("decode", counts)
-        return logits[:, 0]
+        return out
 
     # -- expert counters -----------------------------------------------
     def _note_counts(self, kind: str, counts: List[Any]) -> None:
@@ -875,7 +942,7 @@ class Scheduler:
         """Add the kept counters to the totals and publish each call's
         as ``jax.monitoring`` scalars
         (``/repro/serve/experts/<decode|chunk>/<held_choices|experts_hit>``).
-        Called once the step's logits were read, when the programs that
+        Called once the step's tokens were read, when the programs that
         made the counters have finished and their copies landed."""
         for kind, c in self._pending_counts:
             held, hit = (int(v) for v in np.asarray(c))
@@ -888,6 +955,26 @@ class Scheduler:
             jax.monitoring.record_scalar(
                 f"/repro/serve/experts/{kind}/experts_hit", hit)
         self._pending_counts = []
+
+    def _read_tokens(self, out: jnp.ndarray, active: List[int]) -> List[int]:
+        """The step's token for each active slot, in ``active``'s order.
+        ``out`` is the decode program's (B,) tokens, read in one
+        transfer; on the host-sampler path its (B, vocab) logits, one
+        sampler call (and read) per slot."""
+        if self.sampler is None:
+            self._sampling["program_steps"] += 1
+            self._sampling["token_reads"] += 1
+            toks = np.asarray(out)
+            return [int(toks[slot]) for slot in active]
+        self._sampling["host_steps"] += 1
+        self._sampling["token_reads"] += len(active)
+        toks = []
+        for slot in active:
+            st = self.slot_manager.state(slot)
+            toks.append(self.sampler(
+                out[slot:slot + 1], st.temperature, uid=st.uid,
+                index=self.request_metrics[st.uid].new_tokens))
+        return toks
 
     # -- the step loop -------------------------------------------------
     def step(self) -> int:
@@ -908,22 +995,22 @@ class Scheduler:
             return 0
         with TraceAnnotation("repro.step.decode"):
             if self._decode_engine is not None:
-                logits = self._bucketed_decode(len(active))
+                out = self._bucketed_decode(len(active))
                 active = self.slot_manager.active_slots()  # post-compaction
             else:
                 decode = self._decode_aot or self._decode
-                logits, self.slot_manager.cache, *counts = decode(
-                    self.params, self.slot_manager.cache, self._tokens())
+                self.slot_manager.cache, out, *counts = decode(
+                    self.params, self.slot_manager.cache,
+                    *self._step_inputs())
                 self._note_counts("decode", counts)
-                logits = logits[:, 0]
+            if self.sampler is None:
+                out.copy_to_host_async()
         self.metrics.decode_steps += 1
         self.metrics.decode_slot_steps += len(active)
         with TraceAnnotation("repro.step.sample"):
-            for slot in active:
+            for slot, tok in zip(active, self._read_tokens(out, active)):
                 st = self.slot_manager.state(slot)
                 m = self.request_metrics[st.uid]
-                tok = self.sampler(logits[slot:slot + 1], st.temperature,
-                                   uid=st.uid, index=m.new_tokens)
                 self.generated[st.uid].append(tok)
                 self.last_token[slot, 0] = tok
                 m.new_tokens += 1
@@ -999,6 +1086,14 @@ class Scheduler:
             }
         if self._prefix_cache is not None:
             out["prefix_cache"] = self._prefix_cache.stats()
+        # which path sampled the decode steps, and the token reads each
+        # step made (1 when the program samples, one a slot otherwise)
+        smp = self._sampling
+        steps = smp["program_steps"] + smp["host_steps"]
+        out["sampling"] = {
+            "program_steps": smp["program_steps"],
+            "host_steps": smp["host_steps"],
+            "reads_per_step": smp["token_reads"] / steps if steps else None}
         if self._stats:
             # routed token-choices that fell on the experts held here,
             # and held experts hit, summed over the expert layers and

@@ -190,6 +190,46 @@ def test_served_with_chunked_prefill(setup):
         assert 0 < ex[k]["experts_hit"] <= ex[k]["held_choices"]
 
 
+def test_decode_program_returns_tokens_then_counters(setup):
+    """The MoE decode program samples its tokens and still returns the
+    expert counters as its trailing outputs; served through it, the
+    same requests give the tokens and counter totals of a host argmax
+    over the program's logits."""
+    import repro
+    from repro.serve import Request
+    cfg, w, arch, _ = setup
+
+    def serve(**kw):
+        exe = repro.compile(arch, repro.CompileOptions(target="engine"),
+                            params=jax.tree.map(jnp.copy, w))
+        sched = repro.serve(exe, repro.SchedulerOptions(
+            slots=2, max_len=32, prefill_chunk=8), **kw)
+        outs = jax.eval_shape(sched._decode, sched.params,
+                              sched.slot_manager.cache, *sched._step_specs())
+        for i in range(3):
+            sched.submit(Request(uid=i, prompt=_tokens(10 + 4 * i, seed=i),
+                                 max_new_tokens=4))
+        toks = {c.uid: c.tokens for c in sched.run()}
+        s = sched.summary()
+        sched.shutdown()
+        return outs, toks, s
+
+    outs, toks, s = serve()
+    _, tokens, *counts = outs
+    assert (tokens.shape, tokens.dtype) == ((2,), jnp.int32)
+    assert counts and all(c.dtype == jnp.int32 for c in counts)
+    assert s["sampling"]["reads_per_step"] == 1.0
+
+    def argmax(logits, temperature, *, uid, index):
+        return int(np.argmax(np.asarray(logits)[0]))
+
+    outs_h, toks_h, s_h = serve(sampler=argmax)
+    assert outs_h[1].shape == (2, cfg["vocab_size"])     # the logits
+    assert toks == toks_h
+    assert s["experts"] == s_h["experts"]
+    assert s["experts"]["decode"]["calls"] == s["decode_steps"] > 0
+
+
 # ---------------------------------------------------------------------------
 # The expert layer
 # ---------------------------------------------------------------------------

@@ -13,12 +13,13 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 import repro
 from repro.configs import get_config
 from repro.models import get_model
 from repro.serve import (QueueFullError, Request, Scheduler,
-                         SchedulerOptions)
+                         SchedulerOptions, sample_rows)
 
 
 @pytest.fixture(scope="module")
@@ -427,6 +428,92 @@ def test_chunked_prefill_scheduler_tokens_identical(setup):
     # chunked prefill replaces the padded length-bucket prefill engine
     assert "prefill" not in summ_b["runtime"]
     assert "chunk" in summ_b["runtime"]
+
+
+# ------------------------------------------- sampling inside the program
+def _host_argmax(logits, temperature, *, uid, index):
+    """Greedy sampling on the host, one read per slot."""
+    return int(np.argmax(np.asarray(logits)[0]))
+
+
+@pytest.mark.parametrize("path", ["fixed", "bucketed", "chunked"])
+def test_program_sampling_matches_host_argmax(setup, path):
+    """Greedy tokens sampled inside the decode program (and the first
+    token's program) equal a host argmax over the same logits, token
+    for token, on each decode path."""
+    cfg, m, params = setup
+    opts = {"fixed": {},
+            "bucketed": {"buckets": repro.BucketPolicy.default(
+                max_batch=3, max_len=64)},
+            "chunked": {"prefill_chunk": 8}}[path]
+    head = (np.arange(20, dtype=np.int32) * 7 + 2) % cfg.vocab
+    reqs = _mixed_requests(cfg.vocab, head=head, max_new=6)
+    want, host = _tokens(m, params, reqs, slots=3, max_len=64,
+                         sampler=_host_argmax, **opts)
+    got, prog = _tokens(m, params, reqs, slots=3, max_len=64, **opts)
+    assert got == want
+    assert prog["sampling"]["program_steps"] == prog["decode_steps"] > 0
+    assert host["sampling"]["host_steps"] == host["decode_steps"] > 0
+
+
+def test_sample_rows_mixed_batch():
+    """Rows at temperature 0 or below take their argmax whatever their
+    neighbours draw; one key gives one set of tokens; every token lies
+    in the vocabulary; the hot rows draw (another key, other tokens)."""
+    vocab = 97
+    logits = jnp.asarray(np.random.default_rng(0).standard_normal(
+        (6, vocab)), jnp.float32)
+    temps = jnp.asarray([0.0, 0.8, 0.0, 1.5, -1.0, 3.0], jnp.float32)
+    greedy = np.argmax(np.asarray(logits), axis=-1)
+    cold = np.asarray(temps) <= 0
+    key = lambda n: jax.random.fold_in(jax.random.PRNGKey(3), n)
+
+    toks = np.asarray(sample_rows(logits, temps, key(7)))
+    assert toks.dtype == np.int32 and toks.shape == (6,)
+    assert ((toks >= 0) & (toks < vocab)).all()
+    np.testing.assert_array_equal(toks[cold], greedy[cold])
+    np.testing.assert_array_equal(
+        np.asarray(sample_rows(logits, jnp.zeros(6), key(7))), greedy)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(sample_rows)(logits, temps, key(7))), toks)
+
+    draws = np.stack([np.asarray(sample_rows(logits, temps, key(n)))
+                      for n in range(16)])
+    assert ((draws >= 0) & (draws < vocab)).all()
+    assert (draws[:, cold] == greedy[cold]).all()
+    assert len(set(draws[:, 5].tolist())) > 1     # t = 3 draws vary
+
+
+def test_program_sampling_seeded(setup):
+    """Requests at a temperature above 0: the same seed serves the same
+    tokens, another seed other tokens."""
+    cfg, m, params = setup
+    reqs = [Request(uid=r.uid, prompt=r.prompt, max_new_tokens=6,
+                    temperature=0.0 if r.uid % 3 == 0 else 1.0)
+            for r in _mixed_requests(cfg.vocab)]
+    a, _ = _tokens(m, params, reqs, slots=3, max_len=48, seed=5)
+    b, _ = _tokens(m, params, reqs, slots=3, max_len=48, seed=5)
+    c, _ = _tokens(m, params, reqs, slots=3, max_len=48, seed=6)
+    assert a == b
+    assert a != c
+    assert all(0 <= t < cfg.vocab for toks in a.values() for t in toks)
+
+
+def test_sampling_summary_names_the_path(setup):
+    """``summary()["sampling"]``: one token read a decode step when the
+    program samples; one a slot, and no program step, with a host
+    sampler."""
+    cfg, m, params = setup
+    reqs = _mixed_requests(cfg.vocab, n=4, max_new=4)
+    _, prog = _tokens(m, params, reqs, slots=2, max_len=48)
+    assert prog["sampling"] == {"program_steps": prog["decode_steps"],
+                                "host_steps": 0, "reads_per_step": 1.0}
+    _, host = _tokens(m, params, reqs, slots=2, max_len=48,
+                      sampler=ScriptedSampler({}))
+    assert host["sampling"]["program_steps"] == 0
+    assert host["sampling"]["host_steps"] == host["decode_steps"]
+    assert host["sampling"]["reads_per_step"] == pytest.approx(
+        host["mean_batch_occupancy"])
 
 
 def test_prefix_sharing_bit_identity_and_head_prefilled_once(setup):

@@ -6,7 +6,9 @@ compiler accepts (block shapes against the (8, 128) tiling, VMEM use).
 Here each kernel is lowered and compiled for one chip of a ``v5e:2x2``
 topology and its program must hold a ``tpu_custom_call`` under the
 kernel's own name (``pallas_call(name=...)``), which is the name a
-profiler trace gives its operations.  The
+profiler trace gives its operations.  The serving decode program's
+sampling (``repro.serve.sample_rows``, plain XLA) is compiled here too,
+at both served vocabularies.  The
 topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all
 import this file.
@@ -159,3 +161,19 @@ def test_moe_gmm_compiles(one_chip, tokens):
                     ((1,), jnp.int32), ((1,), jnp.int32))
     assert text.count("tpu_custom_call") == 2
     assert "%moe_gmm" in text
+
+
+@pytest.mark.parametrize("vocab", [152064, 129280], ids=["qwen", "dsv3"])
+def test_sample_rows_compiles(one_chip, vocab):
+    """The decode program's sampling over a 32-slot step's float32
+    logits: the argmax, and the categorical draw behind a conditional
+    (both branches compiled; the chip picks one)."""
+    from repro.serve import sample_rows
+
+    def sample(logits, temps, n):
+        key = jax.random.fold_in(jax.random.PRNGKey(0), n)
+        return sample_rows(logits, temps, key)
+
+    text = _compile(sample, one_chip, ((32, vocab), jnp.float32),
+                    ((32,), jnp.float32), ((), jnp.uint32))
+    assert "conditional" in text
