@@ -1,7 +1,7 @@
 """The "engine" target: framework-scale models behind the same API.
 
 ``repro.compile(cfg_or_model, CompileOptions(target="engine"))`` wraps
-``models.api.Model`` + ``inference.Engine`` in the Executable protocol,
+``models.api.Model`` + ``repro.serve`` in the Executable protocol,
 so the LLM stack and the paper's CNN compiler are driven identically:
 
     exe = repro.compile(get_config("qwen2.5-14b", smoke=True),

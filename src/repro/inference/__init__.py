@@ -1,2 +1,1 @@
-from .engine import Engine, Request, Completion
 from .fold_norms import fold_norms

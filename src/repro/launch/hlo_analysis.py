@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -297,12 +297,70 @@ def flops_from_pre(text: str, chips: int = 1) -> Tuple[float, int]:
 # ---------------------------------------------------------------------------
 # Bytes + collectives from the post-optimization module (per-device)
 # ---------------------------------------------------------------------------
+def _collective_ops(comps: Dict[str, Computation], mult: Dict[str, float]
+                    ) -> Iterator[Tuple[str, Op, float]]:
+    """Each collective op as (kind, op, result-buffer bytes weighted by
+    its enclosing ``while`` trip counts); ``-start`` ops count as their
+    kind."""
+    for cname, comp in comps.items():
+        m = mult.get(cname, 1.0)
+        for op in comp.ops:
+            base = op.opcode[:-6] if op.opcode.endswith("-start") \
+                else op.opcode
+            if base in _COLLECTIVES:
+                yield base, op, m * _tshape_bytes(op.type_str)
+
+
+# replica_groups spellings in post-optimization HLO: explicit
+# ``{{0,2},{1,3}}`` lists and the iota form ``[2,2]<=[4]`` (G groups of
+# S devices: the second dim is the group size).
+_GROUPS_LIST_RE = re.compile(r"replica_groups=\{\{([0-9, ]+)\}")
+_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
+
+
+def collectives_by_axis(texts: Iterable[str],
+                        axes: Sequence[Tuple[str, int]]) -> dict:
+    """Collective counts by kind, and counts and bytes-moved estimates
+    by mesh axis, over post-optimization HLO ``texts`` of programs on a
+    mesh of ``(name, size)`` ``axes``.
+
+    Bytes are each collective's trip-count-weighted result-buffer size
+    (as in :func:`bytes_from_post`); each op is attributed to the axes
+    (of size > 1) whose size matches its replica-group size, split
+    evenly when several share a size, or among all of them when none
+    matches (or the groups are not spelled out)."""
+    live = [(a, s) for a, s in axes if s > 1]
+    per_axis = {a: {"count": 0, "bytes": 0.0} for a, _ in live}
+    counts: Dict[str, int] = {}
+    total = 0.0
+    for text in texts:
+        comps = parse_hlo(text)
+        mult, _ = _multipliers(comps)
+        for kind, op, moved in _collective_ops(comps, mult):
+            counts[kind] = counts.get(kind, 0) + 1
+            total += moved
+            m = _GROUPS_LIST_RE.search(op.line)
+            if m:
+                group = len([x for x in m.group(1).split(",") if x.strip()])
+            else:
+                m = _GROUPS_IOTA_RE.search(op.line)
+                group = int(m.group(2)) if m else 0
+            hit = [a for a, s in live if s == group] or list(per_axis)
+            for a in hit:
+                per_axis[a]["count"] += 1
+                per_axis[a]["bytes"] += moved / len(hit)
+    return {"counts": counts, "per_axis": per_axis,
+            "total_bytes": int(total)}
+
+
 def bytes_from_post(text: str) -> Tuple[float, Dict[str, float], int]:
     """Trip-count-weighted (hbm_bytes, collective bytes by kind,
     unresolved-while count) from post-optimization per-device HLO."""
     comps = parse_hlo(text)
     mult, unresolved = _multipliers(comps)
     coll = {k: 0.0 for k in _COLLECTIVES}
+    for kind, _, moved in _collective_ops(comps, mult):
+        coll[kind] += moved
     hbm = 0.0
     # Fusions whose root is an in-place update (dynamic-update-slice /
     # scatter) alias their operand buffer — XLA writes only the updated
@@ -324,13 +382,9 @@ def bytes_from_post(text: str) -> Tuple[float, Dict[str, float], int]:
             "scatter", "dynamic-update-slice")
     for cname, comp in comps.items():
         m = mult.get(cname, 1.0)
+        if cname.startswith(("fused_", "wrapped_")):
+            continue                # fusion internals don't hit HBM
         for op in comp.ops:
-            base = op.opcode[:-6] if op.opcode.endswith("-start") \
-                else op.opcode
-            if base in _COLLECTIVES:
-                coll[base] += m * _tshape_bytes(op.type_str)
-            if cname.startswith(("fused_", "wrapped_")):
-                continue            # fusion internals don't hit HBM
             if op.opcode in skip or op.opcode.endswith("-done"):
                 continue
             if op.opcode == "fusion":

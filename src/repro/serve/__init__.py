@@ -11,8 +11,8 @@
 
 One scheduler (`Scheduler`), one options object (`SchedulerOptions`),
 per-request metrics (`RequestMetrics`), and a slot/KV-cache manager
-(`SlotManager`) extracted from the legacy ``inference.Engine`` — which
-is now a deprecated shim over this package.
+(`SlotManager`); the cache's placement on a mesh is owned by
+:class:`repro.dist.placement.Placement`.
 
 The module itself is callable — ``repro.serve(executable, options)``
 delegates to :func:`repro.api.serve.serve` — so the package namespace

@@ -38,8 +38,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import math
-import re
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -49,66 +47,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ..dist.mesh import MeshUnavailableError, ensure_mesh_available
+from ..dist.placement import Placement
 from .metrics import RequestMetrics, SchedulerMetrics
 from .options import SchedulerOptions
 from .prefix import PrefixCache, common_prefix_len
 from .slots import SlotManager, SlotState
-
-# replica_groups spellings in post-optimization HLO: explicit
-# ``{{0,2},{1,3}}`` lists and the iota form ``[2,2]<=[4]`` (G groups
-# of S devices — the second dim is the group size).
-_GROUPS_LIST_RE = re.compile(r"replica_groups=\{\{([0-9, ]+)\}")
-_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
-
-
-def _group_size(line: str) -> int:
-    """Devices per replica group of one collective op (0 if unknown)."""
-    m = _GROUPS_LIST_RE.search(line)
-    if m:
-        return len([x for x in m.group(1).split(",") if x.strip()])
-    m = _GROUPS_IOTA_RE.search(line)
-    if m:
-        return int(m.group(2))
-    return 0
-
-
-def _axis_collectives(texts: List[str], spec) -> dict:
-    """Per-mesh-axis collective counts and bytes-moved estimates from
-    compiled post-optimization HLO.
-
-    Bytes are each collective's result-buffer size weighted by its
-    enclosing ``while`` trip counts (the scanned-layers multiplier —
-    see :mod:`repro.launch.hlo_analysis`); each op is attributed to the
-    mesh axes whose size matches its replica-group size, split evenly
-    when several axes share a size.
-    """
-    from ..launch import hlo_analysis as H
-    per_axis = {n: {"count": 0, "bytes": 0.0}
-                for n, s in spec.axes if s > 1}
-    counts: Dict[str, int] = {}
-    total = 0.0
-    for text in texts:
-        comps = H.parse_hlo(text)
-        mult, _ = H._multipliers(comps)
-        for cname, comp in comps.items():
-            m = mult.get(cname, 1.0)
-            for op in comp.ops:
-                base = op.opcode[:-6] if op.opcode.endswith("-start") \
-                    else op.opcode
-                if base not in H._COLLECTIVES:
-                    continue
-                moved = m * H._tshape_bytes(op.type_str)
-                counts[base] = counts.get(base, 0) + 1
-                total += moved
-                k = _group_size(op.line)
-                axes = [a for a, s in spec.axes if s > 1 and s == k] \
-                    or list(per_axis)
-                for a in axes:
-                    per_axis[a]["count"] += 1
-                    per_axis[a]["bytes"] += moved / max(len(axes), 1)
-    return {"counts": counts, "per_axis": per_axis,
-            "total_bytes": int(total)}
 
 
 @dataclasses.dataclass
@@ -224,28 +167,17 @@ class Scheduler:
         self.sampler = sampler      # None: the programs sample
         self.clock = clock
 
-        # data×model-parallel serving (repro.dist): bind the mesh before
-        # any program builds so placements are committed up front.
-        # ``device_source`` is the fault-injection seam: tests shrink the
-        # visible device set and the step loop raises a typed
+        # data×model-parallel serving (repro.dist): the placement binds
+        # the mesh before any program builds, so placements are committed
+        # up front.  ``device_source`` is the fault-injection seam: tests
+        # shrink the visible device set and the step loop raises a typed
         # MeshUnavailableError (recorded in ``summary()["faults"]``).
-        self.mesh = None
-        self._faults: List[dict] = []
-        self._device_source = device_source or jax.devices
-        if options.mesh is not None:
-            ensure_mesh_available(options.mesh, self._device_source())
-            self.mesh = options.mesh.build(self._device_source())
-            from jax.sharding import NamedSharding, PartitionSpec
-            # params replicate; the batched KV cache shards (see
-            # _leaf_sharding) — the data×model split the mesh names.
-            self.params = jax.device_put(
-                self.params, jax.tree.map(
-                    lambda _: NamedSharding(self.mesh, PartitionSpec()),
-                    self.params))
-
-        self.slot_manager = SlotManager(
-            model, options.slots, options.max_len,
-            shard=self._leaf_sharding if self.mesh is not None else None)
+        self.placement = Placement.of(options.mesh,
+                                      device_source or jax.devices)
+        self.params = self.placement.put(self.params)
+        self.slot_manager = SlotManager(model, options.slots,
+                                        options.max_len,
+                                        placement=self.placement)
         self._lock = threading.Lock()
         self._queue: List[Request] = []
         self.done: List[Completion] = []
@@ -271,23 +203,37 @@ class Scheduler:
             kind: {"calls": 0, "held_choices": 0, "experts_hit": 0}
             for kind in ("decode", "chunk")}
 
-        # compiled programs (donated cache: in-place buffer reuse).  A
-        # decode program returns the cache first: donation gives each
-        # donated leaf the first output of its shape, and a (slots,)
-        # int32 token vector ahead of the cache would take the position
-        # vector's buffer.
-        def decode_body(p, c, t, temps, n):
-            logits, c, *counts = model.decode_step(
-                p, self._compute_view(c), t, **self._stats)
-            return (self._constrain_cache(c),
-                    self._head(logits[:, 0], temps, n), *counts)
+        # What every decode program is lowered against: the weights, the
+        # batched cache and the per-step inputs (see _step_inputs), each
+        # as the placement holds it.
+        n = options.slots
+        self._decode_specs = (
+            self.placement.specs(self.params),
+            self.placement.specs(self.slot_manager.cache, cache=True),
+            *self.placement.specs((
+                jax.ShapeDtypeStruct((n, 1), jnp.int32),
+                jax.ShapeDtypeStruct((n,), jnp.float32),
+                jax.ShapeDtypeStruct((), jnp.uint32))))
+        # Padded (length-bucketed) and chunked prefill need a cache that
+        # carries ``pos`` and holds ``max_len`` positions: a ring cache
+        # (all-sliding-window models) holds the window, and padding
+        # would roll real tokens out of it.
+        self._row_cache = jax.eval_shape(
+            lambda: model.init_cache(1, options.max_len))
+        longer = jax.eval_shape(
+            lambda: model.init_cache(1, options.max_len + 1))
+        self._positional = (
+            isinstance(self._row_cache, dict) and "pos" in self._row_cache
+            and any(x.shape != y.shape for x, y in zip(
+                jax.tree.leaves(self._row_cache), jax.tree.leaves(longer))))
 
-        self._decode = jax.jit(decode_body, donate_argnums=(1,))
         self._prefill = jax.jit(lambda p, b, c: model.prefill(p, b, c))
         self._sample_first = jax.jit(self._sample)
 
         # shape-polymorphic serving (repro.runtime): warm programs per
-        # bucket, background compiles.  None = fixed-shape (PR-5) path.
+        # bucket, background compiles.  Without buckets, one decode
+        # program over every slot, compiled here (set-up, not latency).
+        self._decode = None
         self._decode_engine = None
         self._prefill_engine = None
         # chunked prefill + shared-prefix snapshots (both optional)
@@ -298,18 +244,8 @@ class Scheduler:
             self._init_chunking(engine_worker)
         if options.buckets is not None:
             self._init_bucketing(engine_worker)
-
-        # Under a mesh without bucketing, AOT-compile the fixed-shape
-        # decode program at construction (load time, not latency) against
-        # the committed placements: steady-state sharded decode never
-        # stalls on a compile, mirroring the bucketed warm-up guarantee.
-        self._decode_aot = None
-        if self.mesh is not None and self._decode_engine is None:
-            self._decode_aot = self._decode.lower(
-                self._aot_specs(self.params),
-                self._aot_specs(self.slot_manager.cache,
-                                shard=self._leaf_sharding),
-                *self._step_specs()).compile()
+        else:
+            self._decode = self._build_decode(options.slots)
 
     # -- sampling --------------------------------------------------------
     def _sample(self, logits, temps, n):
@@ -331,134 +267,51 @@ class Scheduler:
         self._draws += 1
         return np.uint32(n & 0xFFFFFFFF)
 
-    # -- mesh placement ------------------------------------------------
-    def _rule_axes(self, logical: str):
-        """Mesh axes the logical-axis rule maps to, filtered to axes
-        this mesh actually has (``batch`` → data axes, ``kv_seq`` →
-        the model axes of the flash-decoding KV layout)."""
-        from ..dist.propagate import merged_rules
-        names = set(self.mesh.axis_names)
-        return tuple(a for a in merged_rules().get(logical, ())
-                     if a in names)
+    # -- programs ------------------------------------------------------
+    def _build_decode(self, rows: int):
+        """Compile the decode program that advances the first ``rows``
+        slots (all of them on the fixed path; a batch bucket's rows on
+        the bucketed one).
 
-    def _leaf_sharding(self, leaf, *, compute: bool = False):
-        """NamedSharding for one batched-cache leaf.  Leaves are
-        (L, B, S, ...) except the position vector (B,): the slot
-        (batch) dim shards over the ``batch`` rule's axes and the KV
-        sequence dim over the ``kv_seq`` rule's ("model" — the
-        flash-decoding storage layout).  Dims an axis product doesn't
-        divide stay replicated, so any slots/max_len runs on any mesh.
+        Every decode program takes (and donates) the FULL batched cache
+        and returns it first: donation gives each donated leaf the first
+        output of its shape, and a (slots,) int32 token vector ahead of
+        the cache would take the position vector's buffer.  Below
+        ``slots`` the row slice, the decode step and the KV write-back
+        all happen inside the one program, so the donated buffer is
+        updated in place: no per-step slice or write-back allocations
+        (the pre-allocated step-buffer discipline of the paper's memory
+        planner).  Every program is the XLA module ``decode_body``."""
+        model, place, stats = self.model, self.placement, self._stats
+        slots = self.options.slots
 
-        ``compute=True`` is the decode-time view: batch sharding only.
-        Like the graph-IR path, sharding here is PLACEMENT, never math —
-        row parallelism over ``data`` leaves each row's reduction order
-        exactly the single-device order (bit-identical tokens), while
-        the model-axis seq shards are gathered whole by GSPMD (the
-        per-step all-gather ``summary()["sharding"]`` reports).  The
-        model axis still divides per-device KV-cache memory by its size
-        between steps."""
-        from jax.sharding import NamedSharding, PartitionSpec
-        sizes = dict(self.mesh.shape)
+        def decode_body(p, c, t, temps, n):
+            c = place.view(c)
+            if rows == slots:
+                logits, c, *counts = model.decode_step(p, c, t, **stats)
+            else:
+                sub = jax.tree.map(
+                    lambda l: l[:rows] if l.ndim == 1 else l[:, :rows], c)
+                logits, sub, *counts = model.decode_step(
+                    p, sub, t[:rows], **stats)
+                axis = lambda l: 0 if l.ndim == 1 else 1
+                c = jax.tree.map(
+                    lambda f, s: jax.lax.dynamic_update_slice_in_dim(
+                        f, s, 0, axis=axis(f)), c, sub)
+                temps = temps[:rows]
+            return (place.constrain(c),
+                    self._head(logits[:, 0], temps, n), *counts)
 
-        def fit(dim, axes):
-            axes = [a for a in axes if sizes.get(a, 1) > 1]
-            k = math.prod(sizes[a] for a in axes) if axes else 1
-            if k <= 1 or dim % k:
-                return None
-            return axes[0] if len(axes) == 1 else tuple(axes)
-
-        parts = [None] * leaf.ndim
-        b_dim = 0 if leaf.ndim == 1 else 1
-        parts[b_dim] = fit(leaf.shape[b_dim], self._rule_axes("batch"))
-        if leaf.ndim >= 3 and not compute:
-            parts[2] = fit(leaf.shape[2], self._rule_axes("kv_seq"))
-        return NamedSharding(self.mesh, PartitionSpec(*parts))
-
-    def _compute_view(self, cache):
-        """The traced decode-time view of the stored cache: keep the
-        batch (``data``) sharding, gather the model-axis KV shards
-        whole (see ``_leaf_sharding``)."""
-        if self.mesh is None:
-            return cache
-        return jax.tree.map(
-            lambda l: jax.lax.with_sharding_constraint(
-                l, self._leaf_sharding(l, compute=True)), cache)
-
-    def _constrain_cache(self, cache):
-        """Pin a traced cache pytree to its committed storage placement,
-        so the donated decode output keeps the sharding its AOT program
-        (and the next step's input spec) committed to."""
-        if self.mesh is None:
-            return cache
-        return jax.tree.map(
-            lambda l: jax.lax.with_sharding_constraint(
-                l, self._leaf_sharding(l)), cache)
-
-    def _aot_specs(self, tree, shard: Optional[Callable] = None):
-        """ShapeDtypeStructs for AOT lowering.  Under a mesh every leaf
-        carries its committed NamedSharding (``shard`` per leaf, else
-        replicated), so the compiled programs accept exactly the arrays
-        the scheduler holds — AOT programs reject committed arguments
-        whose placement disagrees with their input shardings."""
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        def one(a):
-            if self.mesh is None:
-                return jax.ShapeDtypeStruct(a.shape, a.dtype)
-            s = shard(a) if shard is not None \
-                else NamedSharding(self.mesh, PartitionSpec())
-            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)
-
-        return jax.tree.map(one, tree)
-
-    def _step_specs(self) -> tuple:
-        """Specs of the decode program's per-step inputs (see
-        ``_step_inputs``)."""
-        n = self.options.slots
-        return self._aot_specs((
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((), jnp.uint32)))
+        return jax.jit(decode_body, donate_argnums=(1,)).lower(
+            *self._decode_specs).compile()
 
     def _step_inputs(self) -> tuple:
         """The decode program's per-step inputs: the last-token batch,
         the slots' temperatures and the draw index, in one transfer,
-        placed as its AOT lowering committed to (replicated under a
-        mesh)."""
-        args = (self.last_token, self.temperatures, self._next_draw())
-        if self.mesh is None:
-            return jax.device_put(args)
-        from jax.sharding import NamedSharding, PartitionSpec
-        return jax.device_put(args, NamedSharding(self.mesh,
-                                                  PartitionSpec()))
-
-    def _check_mesh(self) -> None:
-        """Step-loop fault check: raise (and record) a typed
-        :class:`MeshUnavailableError` naming the unfillable axes when
-        the visible device set shrank below what the mesh needs."""
-        if self.options.mesh is None:
-            return
-        try:
-            ensure_mesh_available(self.options.mesh, self._device_source())
-        except MeshUnavailableError as e:
-            self._faults.append({
-                "at": self.clock(), "mesh": e.spec.describe(),
-                "needed": e.needed, "available": e.available,
-                "missing_axes": list(e.missing_axes)})
-            raise
-
-    # -- bucketed engines ----------------------------------------------
-    def _cache_grows_with_max_len(self) -> bool:
-        """False for ring caches (all-sliding-window models), whose
-        capacity is the window, not ``max_len``.  Padded prefill would
-        roll real tokens out of a ring, so length bucketing is only
-        sound when the cache actually holds ``max_len`` positions."""
-        a = jax.eval_shape(
-            lambda: self.model.init_cache(1, self.options.max_len))
-        b = jax.eval_shape(
-            lambda: self.model.init_cache(1, self.options.max_len + 1))
-        return any(x.shape != y.shape for x, y in
-                   zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+        placed as the program's lowering committed to (replicated under
+        a mesh)."""
+        return self.placement.put(
+            (self.last_token, self.temperatures, self._next_draw()))
 
     def _init_bucketing(self, worker: str) -> None:
         from ..runtime.buckets import Bucket, BucketPolicy
@@ -466,52 +319,10 @@ class Scheduler:
         opts = self.options
         policy = opts.buckets.clip(max_batch=opts.slots,
                                    max_len=opts.max_len)
-        cache_spec = jax.eval_shape(
-            lambda: self.model.init_cache(1, opts.max_len))
-        params_spec = self._aot_specs(self.params)
-        len_ok = (policy.len_buckets
-                  and isinstance(cache_spec, dict) and "pos" in cache_spec
-                  and self._cache_grows_with_max_len())
-
-        full_spec = self._aot_specs(
-            jax.eval_shape(
-                lambda: self.model.init_cache(opts.slots, opts.max_len)),
-            shard=self._leaf_sharding if self.mesh is not None else None)
-        step_specs = self._step_specs()
-
-        def build_decode(bucket):
-            # EVERY bucket's program takes (and donates) the FULL
-            # batched cache: the row slice, the decode step and the KV
-            # write-back all happen inside one compiled program, so the
-            # donated buffer is updated in place — no per-step slice /
-            # write-back allocations at the JAX level (the pre-allocated
-            # step-buffer discipline of the paper's memory planner).
-            b = bucket.batch
-
-            def step(p, c, t, temps, n):
-                c = self._compute_view(c)
-                if b >= opts.slots:
-                    logits, c, *counts = self.model.decode_step(
-                        p, c, t, **self._stats)
-                    return (self._constrain_cache(c),
-                            self._head(logits[:, 0], temps, n), *counts)
-                sub = jax.tree.map(
-                    lambda l: l[:b] if l.ndim == 1 else l[:, :b], c)
-                logits, sub, *counts = self.model.decode_step(
-                    p, sub, t[:b], **self._stats)
-                axis = lambda l: 0 if l.ndim == 1 else 1
-                new_c = jax.tree.map(
-                    lambda f, s: jax.lax.dynamic_update_slice_in_dim(
-                        f, s, 0, axis=axis(f)), c, sub)
-                return (self._constrain_cache(new_c),
-                        self._head(logits[:, 0], temps[:b], n), *counts)
-
-            fn = jax.jit(step, donate_argnums=(1,))
-            return fn.lower(params_spec, full_spec, *step_specs).compile()
-
         self._decode_engine = EngineCache(
             BucketPolicy(batch_buckets=policy.batch_buckets),
-            build_decode, worker=worker, clock=self.clock)
+            lambda bucket: self._build_decode(min(bucket.batch, opts.slots)),
+            worker=worker, clock=self.clock)
         # the full-slots program covers every batch, so compiling it
         # synchronously here (load time, not latency) guarantees the
         # decode path never stalls; smaller buckets fill in behind it
@@ -520,8 +331,10 @@ class Scheduler:
 
         # chunked prefill supersedes padded whole-prompt prefill: every
         # prompt runs through the (single-bucket) chunk program instead
-        if not len_ok or self._chunk_engine is not None:
+        if (not (policy.len_buckets and self._positional)
+                or self._chunk_engine is not None):
             return
+        place, p_spec = self.placement, self._decode_specs[0]
 
         def build_prefill(bucket):
             from ..configs.base import extra_input_specs
@@ -529,11 +342,10 @@ class Scheduler:
                                                      jnp.int32)}
             for name, (shape, dt) in extra_input_specs(self.cfg).items():
                 b_spec[name] = jax.ShapeDtypeStruct(shape, dt)
-            l_spec = jax.ShapeDtypeStruct((), jnp.int32)
             fn = jax.jit(self._prefill_fixup)
-            return fn.lower(params_spec, self._aot_specs(b_spec),
-                            self._aot_specs(cache_spec),
-                            self._aot_specs(l_spec)).compile()
+            return fn.lower(p_spec, *place.specs((
+                b_spec, self._row_cache,
+                jax.ShapeDtypeStruct((), jnp.int32)))).compile()
 
         self._prefill_engine = EngineCache(
             BucketPolicy(batch_buckets=(1,),
@@ -559,26 +371,21 @@ class Scheduler:
         from ..runtime.engine_cache import EngineCache
         opts = self.options
         supports = getattr(self.model, "supports_chunked_prefill", None)
-        cache_spec = jax.eval_shape(
-            lambda: self.model.init_cache(1, opts.max_len))
-        if not (supports is not None and supports()
-                and isinstance(cache_spec, dict) and "pos" in cache_spec
-                and self._cache_grows_with_max_len()):
+        if not (supports is not None and supports() and self._positional):
             return
-        params_spec = self._aot_specs(self.params)
+        place, p_spec = self.placement, self._decode_specs[0]
 
         def build_chunk(bucket):
-            t_spec = self._aot_specs(
-                jax.ShapeDtypeStruct((1, bucket.length), jnp.int32))
-            s_spec = self._aot_specs(jax.ShapeDtypeStruct((), jnp.int32))
+            t_spec, c_spec, s_spec = place.specs((
+                jax.ShapeDtypeStruct((1, bucket.length), jnp.int32),
+                self._row_cache, jax.ShapeDtypeStruct((), jnp.int32)))
             # the single-row cache is donated: each chunk fills it in
             # place (PrefixCache copies before/after, never aliases it)
             fn = jax.jit(
                 lambda p, t, c, s, n: self.model.prefill_chunk(
                     p, t, c, s, n, **self._stats),
                 donate_argnums=(2,))
-            return fn.lower(params_spec, t_spec, self._aot_specs(cache_spec),
-                            s_spec, s_spec).compile()
+            return fn.lower(p_spec, t_spec, c_spec, s_spec, s_spec).compile()
 
         self._chunk_engine = EngineCache(
             BucketPolicy(batch_buckets=(1,),
@@ -911,24 +718,20 @@ class Scheduler:
         self._pending.append(c)
 
     # -- bucketed decode -----------------------------------------------
-    def _bucketed_decode(self, k: int) -> jnp.ndarray:
-        """One decode step at the best warm batch bucket for ``k``
-        active slots.  Compacts actives into rows ``[0, k)`` and runs
-        the bucket's program over the FULL donated cache — the row
-        slice and KV write-back happen inside the compiled program, so
-        the cache buffer is reused in place every step (bit-identical
-        per row to decoding at the full slot count, minus the work for
-        the empty rows; the returned tokens, or logits, cover the
-        bucket's rows)."""
+    def _bucket_program(self, k: int):
+        """The best warm batch bucket's decode program for ``k`` active
+        slots, and the active slots after compacting them into rows
+        ``[0, k)``: the program slices those rows out of the FULL
+        donated cache and writes them back in place (bit-identical per
+        row to decoding at the full slot count, minus the work for the
+        empty rows; the returned tokens, or logits, cover the bucket's
+        rows)."""
         for src, dst in self.slot_manager.compact():
             self.last_token[dst, 0] = self.last_token[src, 0]
             self.temperatures[dst] = self.temperatures[src]
             self.temperatures[src] = 0.0
         entry, _, _ = self._decode_engine.get(k)
-        self.slot_manager.cache, out, *counts = entry(
-            self.params, self.slot_manager.cache, *self._step_inputs())
-        self._note_counts("decode", counts)
-        return out
+        return entry, self.slot_manager.active_slots()
 
     # -- expert counters -----------------------------------------------
     def _note_counts(self, kind: str, counts: List[Any]) -> None:
@@ -983,7 +786,7 @@ class Scheduler:
         active afterwards."""
         # Each phase that runs is one leaf profiler span (none encloses
         # another), so a trace shows which phase the host was in.
-        self._check_mesh()          # no-op unless mesh-parallel serving
+        self.placement.check(self.clock)   # no-op on one device
         with (TraceAnnotation("repro.step.admit") if self._queue
               else contextlib.nullcontext()):
             self._admit_free_slots()
@@ -994,15 +797,12 @@ class Scheduler:
         if not active:
             return 0
         with TraceAnnotation("repro.step.decode"):
-            if self._decode_engine is not None:
-                out = self._bucketed_decode(len(active))
-                active = self.slot_manager.active_slots()  # post-compaction
-            else:
-                decode = self._decode_aot or self._decode
-                self.slot_manager.cache, out, *counts = decode(
-                    self.params, self.slot_manager.cache,
-                    *self._step_inputs())
-                self._note_counts("decode", counts)
+            decode = self._decode
+            if decode is None:
+                decode, active = self._bucket_program(len(active))
+            self.slot_manager.cache, out, *counts = decode(
+                self.params, self.slot_manager.cache, *self._step_inputs())
+            self._note_counts("decode", counts)
             if self.sampler is None:
                 out.copy_to_host_async()
         self.metrics.decode_steps += 1
@@ -1058,7 +858,8 @@ class Scheduler:
         """Aggregate metrics: counters and TTFT/queue percentiles, plus
         runtime engine stats, the active-precision audit record, and
         chunked-prefill / prefix-cache sections when those features are
-        active."""
+        active; under a mesh, the placement's faults and collective
+        counts (:meth:`repro.dist.placement.Placement.summary`)."""
         engines = {}
         if self._decode_engine is not None:
             engines["decode"] = self._decode_engine.stats()
@@ -1101,35 +902,8 @@ class Scheduler:
             self._flush_counts()
             out["experts"] = {k: dict(v)
                               for k, v in self._expert_counts.items()}
-        if self.options.mesh is not None:
-            out["faults"] = [dict(f) for f in self._faults]
-            out["sharding"] = self._sharding_summary()
+        programs = [self._decode] if self._decode is not None else [
+            self._decode_engine.peek(b)
+            for b in self._decode_engine.warm_buckets()]
+        out.update(self.placement.summary(programs))
         return out
-
-    def _sharding_summary(self) -> dict:
-        """Mesh description plus per-axis collective counts and
-        bytes-moved estimates, read from the compiled decode program(s)'
-        post-optimization HLO (see :func:`_axis_collectives`)."""
-        spec = self.options.mesh
-        texts: List[str] = []
-        programs = []
-        if self._decode_aot is not None:
-            programs.append(self._decode_aot)
-        elif self._decode_engine is not None:
-            programs.extend(
-                self._decode_engine.peek(b)
-                for b in self._decode_engine.warm_buckets())
-        for prog in programs:
-            try:
-                texts.append(prog.as_text())
-            except Exception:
-                continue               # text unavailable: skip, not fail
-        return {"mesh": spec.describe(), "devices": spec.size,
-                "decode_programs": len(texts),
-                "collectives": _axis_collectives(texts, spec)}
-
-    # legacy Engine attribute surface, used by the deprecated shim
-    @property
-    def cache(self) -> Any:
-        """The batched KV cache (legacy ``Engine.cache`` surface)."""
-        return self.slot_manager.cache

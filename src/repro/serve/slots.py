@@ -6,17 +6,16 @@ layout") and the per-slot host bookkeeping.  Admission splices a
 freshly prefilled single-row cache into a free slot; eviction just
 marks the slot free — the row is overwritten by the next admission, so
 no memory moves on retire.
-
-Extracted and generalized from ``inference.engine.Engine``'s
-``_splice_impl`` / ``_fill_free_slots`` / ``_retire``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, List, Optional, Tuple
 
 import jax
+
+from ..dist.placement import Placement
 
 
 @dataclasses.dataclass
@@ -34,36 +33,25 @@ class SlotManager:
     admission writes one request's rows in, retirement frees them."""
 
     def __init__(self, model, slots: int, max_len: int, *,
-                 shard: Optional[Callable[[Any], Any]] = None) -> None:
+                 placement: Optional[Placement] = None) -> None:
         self.slots = slots
         self.max_len = max_len
-        self.cache = model.init_cache(slots, max_len)
+        # The cache is placed once here and every cache-mutating program
+        # re-constrains its output, so the batched cache never drifts off
+        # its placement: the compiled decode programs commit to it.
+        place = placement or Placement()
+        self.cache = place.put(model.init_cache(slots, max_len), cache=True)
         self._states: List[Optional[SlotState]] = [None] * slots
-        # Mesh-aware serving: ``shard`` maps a cache leaf to its
-        # NamedSharding.  The cache is placed once here and every
-        # cache-mutating program re-constrains its output, so the
-        # batched cache NEVER drifts off its placement — the AOT decode
-        # programs commit to exactly these shardings.
-        self._shard = shard
-        if shard is not None:
-            self.cache = jax.tree.map(
-                lambda l: jax.device_put(l, shard(l)), self.cache)
-
-        def constrain(tree):
-            if shard is None:
-                return tree
-            return jax.tree.map(
-                lambda l: jax.lax.with_sharding_constraint(l, shard(l)),
-                tree)
 
         # donate the batched cache: splice writes one row in place
         self._splice = jax.jit(
-            lambda c, o, slot: constrain(self._splice_impl(c, o, slot)),
+            lambda c, o, slot: place.constrain(
+                self._splice_impl(c, o, slot)),
             donate_argnums=(0,), static_argnums=(2,))
         # row move for compaction; src/dst are traced, so one program
         # serves every (src, dst) pair
         self._move = jax.jit(
-            lambda c, s, d: constrain(self._move_impl(c, s, d)),
+            lambda c, s, d: place.constrain(self._move_impl(c, s, d)),
             donate_argnums=(0,))
 
     # ------------------------------------------------------------------

@@ -204,8 +204,7 @@ def test_decode_program_returns_tokens_then_counters(setup):
                             params=jax.tree.map(jnp.copy, w))
         sched = repro.serve(exe, repro.SchedulerOptions(
             slots=2, max_len=32, prefill_chunk=8), **kw)
-        outs = jax.eval_shape(sched._decode, sched.params,
-                              sched.slot_manager.cache, *sched._step_specs())
+        outs = sched._decode.out_info
         for i in range(3):
             sched.submit(Request(uid=i, prompt=_tokens(10 + 4 * i, seed=i),
                                  max_new_tokens=4))

@@ -1,4 +1,4 @@
-"""Serving engine: continuous batching, determinism, norm-fold."""
+"""Serving: continuous batching, determinism, norm-fold."""
 
 import numpy as np
 import pytest
@@ -7,8 +7,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.inference import Engine, Request, fold_norms
+from repro.inference import fold_norms
 from repro.models import get_model
+from repro.serve import Request, Scheduler, SchedulerOptions
 
 
 @pytest.fixture(scope="module")
@@ -92,13 +93,17 @@ def test_serve_hands_the_weights_over(setup):
         sched.shutdown()
 
 
+def _scheduler(m, params, slots):
+    return Scheduler(m, params, SchedulerOptions(slots=slots, max_len=48))
+
+
 def test_engine_drains_queue(setup):
     cfg, m, params = setup
-    eng = Engine(m, params, slots=2, max_len=48)
+    sched = _scheduler(m, params, 2)
     for i in range(5):
-        eng.submit(Request(uid=i, prompt=np.arange(4 + i) % cfg.vocab,
-                           max_new_tokens=5))
-    done = eng.run()
+        sched.submit(Request(uid=i, prompt=np.arange(4 + i) % cfg.vocab,
+                             max_new_tokens=5))
+    done = sched.run()
     assert sorted(c.uid for c in done) == list(range(5))
     assert all(len(c.tokens) == 5 for c in done)
 
@@ -106,11 +111,11 @@ def test_engine_drains_queue(setup):
 def test_batched_equals_solo(setup):
     cfg, m, params = setup
     prompt = np.arange(6) % cfg.vocab
-    solo = Engine(m, params, slots=1, max_len=48)
+    solo = _scheduler(m, params, 1)
     solo.submit(Request(uid=0, prompt=prompt, max_new_tokens=6))
     want = solo.run()[0].tokens
 
-    crowd = Engine(m, params, slots=3, max_len=48)
+    crowd = _scheduler(m, params, 3)
     for i in range(4):
         crowd.submit(Request(uid=i, prompt=prompt if i == 2 else
                              (np.arange(3 + i) * 7) % cfg.vocab,
@@ -121,23 +126,23 @@ def test_batched_equals_solo(setup):
 
 def test_eos_stops_generation(setup):
     cfg, m, params = setup
-    eng = Engine(m, params, slots=1, max_len=48)
+    sched = _scheduler(m, params, 1)
     # Find the first greedy token, then use it as EOS for a second run.
-    eng.submit(Request(uid=0, prompt=np.arange(5) % cfg.vocab,
-                       max_new_tokens=4))
-    first = eng.run()[0].tokens
-    eng2 = Engine(m, params, slots=1, max_len=48)
-    eng2.submit(Request(uid=0, prompt=np.arange(5) % cfg.vocab,
-                        max_new_tokens=32, eos_id=int(first[1])))
-    out = eng2.run()[0].tokens
+    sched.submit(Request(uid=0, prompt=np.arange(5) % cfg.vocab,
+                         max_new_tokens=4))
+    first = sched.run()[0].tokens
+    again = _scheduler(m, params, 1)
+    again.submit(Request(uid=0, prompt=np.arange(5) % cfg.vocab,
+                         max_new_tokens=32, eos_id=int(first[1])))
+    out = again.run()[0].tokens
     assert out[-1] == first[1] and len(out) <= 32
 
 
 def test_engine_cache_donation_structure(setup):
     """After many steps the cache pytree keeps its structure/shape."""
     cfg, m, params = setup
-    eng = Engine(m, params, slots=2, max_len=48)
-    eng.submit(Request(uid=0, prompt=np.arange(4) % cfg.vocab,
-                       max_new_tokens=12))
-    eng.run()
-    assert eng.cache["c1"].shape[1] == 2      # slots preserved
+    sched = _scheduler(m, params, 2)
+    sched.submit(Request(uid=0, prompt=np.arange(4) % cfg.vocab,
+                         max_new_tokens=12))
+    sched.run()
+    assert sched.slot_manager.cache["c1"].shape[1] == 2   # slots preserved

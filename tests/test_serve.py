@@ -4,10 +4,8 @@ eviction, rebatch-vs-sequential equivalence, metrics accounting.
 The deterministic step-loop tests drive the scheduler with a scripted
 token sampler and a fake clock, so every admission, eviction and
 timestamp is asserted exactly; the equivalence tests run the real
-greedy sampler against the legacy sequential ``Engine``.
+greedy sampler against a sequential one-slot scheduler.
 """
-
-import warnings
 
 import numpy as np
 import pytest
@@ -82,19 +80,6 @@ def test_serve_rejects_graph_executables():
                         repro.CompileOptions(target="jit"))
     with pytest.raises(TypeError, match="target='engine'"):
         repro.serve(exe)
-
-
-def test_engine_shim_deprecation_warns_once(setup):
-    cfg, m, params = setup
-    import repro.inference.engine as legacy
-    legacy._warned = False
-    with pytest.warns(DeprecationWarning, match="repro.serve"):
-        legacy.Engine(m, params, slots=1, max_len=32, fold=False)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        legacy.Engine(m, params, slots=1, max_len=32, fold=False)
-    assert not any(issubclass(w.category, DeprecationWarning)
-                   for w in caught)
 
 
 # -------------------------------------------------------------- admission
@@ -188,13 +173,12 @@ def test_rebatched_matches_sequential_engine(setup):
     prompts = {uid: (np.arange(3 + (uid % 4)) * (uid + 2)) % cfg.vocab
                for uid in range(10)}
 
-    # sequential reference: the deprecated one-slot Engine
-    from repro.inference import Engine
+    # sequential reference: a one-slot scheduler per request
     want = {}
     for uid, prompt in prompts.items():
-        eng = Engine(m, params, slots=1, max_len=48, fold=False)
-        eng.submit(Request(uid=uid, prompt=prompt, max_new_tokens=6))
-        want[uid] = eng.run()[0].tokens
+        solo = _sched(m, params, slots=1, max_len=48)
+        solo.submit(Request(uid=uid, prompt=prompt, max_new_tokens=6))
+        want[uid] = solo.run()[0].tokens
 
     # concurrent: 8 slots, first 8 requests up front, 2 arrive mid-loop
     sched = _sched(m, params, slots=8, max_len=48)
@@ -436,16 +420,18 @@ def _host_argmax(logits, temperature, *, uid, index):
     return int(np.argmax(np.asarray(logits)[0]))
 
 
-@pytest.mark.parametrize("path", ["fixed", "bucketed", "chunked"])
+@pytest.mark.parametrize("path", ["fixed", "bucketed", "chunked", "mesh"])
 def test_program_sampling_matches_host_argmax(setup, path):
     """Greedy tokens sampled inside the decode program (and the first
     token's program) equal a host argmax over the same logits, token
-    for token, on each decode path."""
+    for token, on each decode path (``mesh``: the fixed path placed on
+    a one-device mesh)."""
     cfg, m, params = setup
     opts = {"fixed": {},
             "bucketed": {"buckets": repro.BucketPolicy.default(
                 max_batch=3, max_len=64)},
-            "chunked": {"prefill_chunk": 8}}[path]
+            "chunked": {"prefill_chunk": 8},
+            "mesh": {"mesh": "data=1"}}[path]
     head = (np.arange(20, dtype=np.int32) * 7 + 2) % cfg.vocab
     reqs = _mixed_requests(cfg.vocab, head=head, max_new=6)
     want, host = _tokens(m, params, reqs, slots=3, max_len=64,
